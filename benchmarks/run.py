@@ -59,8 +59,7 @@ sys.path.insert(0, "src")                      # repo-root invocation
 
 from repro.core import PROFILES
 
-from benchmarks import framework_benches, modeled, paper_figures, \
-    roofline_report
+from benchmarks import framework_benches, modeled, paper_figures
 from benchmarks.common import atomic_write_json, csv_rows, print_rows
 
 
@@ -192,17 +191,6 @@ def main(argv=None) -> None:
     modeled.DEFAULT_PROFILE = args.profile
     modeled.AUDIT = args.audit
     csv, json_rows = collect(quick=args.quick)
-
-    # roofline tables from dry-run artifacts (if present)
-    try:
-        roofline_report.main()
-        for mesh in ("16-16", "2-16-16"):
-            csv += roofline_report.csv(roofline_report.load("base", mesh))
-        for v in roofline_report.VARIANTS:
-            csv += roofline_report.csv(
-                roofline_report.load(v, "16-16"), table=f"roofline.{v}")
-    except Exception as e:                      # dry-run not executed yet
-        print(f"(roofline tables unavailable: {e})")
 
     print("\n# CSV: name,us_per_call,derived")
     for line in csv:

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import multiprocessing
 import queue as queue_mod
+import sys
 import threading
 import time
 import traceback
@@ -337,6 +338,24 @@ def _worker_main(runtime, tid: int, cmdq, resq, barrier) -> None:
             resq.put((tid, "error", traceback.format_exc()))
 
 
+def _refuse_fork_holding_accelerator() -> None:
+    """Raise if this process has initialised a non-CPU JAX backend: a
+    forked child cannot use the chip its parent holds, so its kernels
+    would fail or hang.  Probes without initialising any backend."""
+    if "jax" not in sys.modules:
+        return
+    # jax has no public probe that leaves uninitialised backends alone
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return
+    held = sorted(p for p in xla_bridge.backends() if p != "cpu")
+    if held:
+        raise RuntimeError(
+            f"refusing to fork workers: this process holds the {held} "
+            "JAX backend, which forked children cannot use; drive the "
+            "device from one process (threads backend)")
+
+
 class WorkerPool:
     """``n`` fork()ed processes, each driving one Handle against the
     runtime's shared-memory board.  See module docstring for the
@@ -354,6 +373,7 @@ class WorkerPool:
         if max(tids) >= runtime.n_threads:
             raise ValueError(f"tids {tids} exceed runtime.n_threads="
                              f"{runtime.n_threads}")
+        _refuse_fork_holding_accelerator()
         self.runtime = runtime
         self.tids = tids
         ctx = multiprocessing.get_context("fork")

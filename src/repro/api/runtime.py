@@ -151,6 +151,12 @@ class CombiningRuntime:
         drain through its own modeled device, DESIGN.md §8)."""
         adapter = get_adapter(kind, protocol)
         nvm = self._ensure_nvm()
+        if kw.get("vector_apply") and getattr(nvm.backend, "kind",
+                                              None) == "shm":
+            raise ValueError(
+                "vector_apply=True needs the threads backend: shm rounds "
+                "run in forked worker processes, and a forked child "
+                "cannot use the chip its parent holds")
         if nvm.segments > 1:
             if segment is None:
                 segment = self._next_segment

@@ -13,23 +13,13 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from .nvm import NVM
 
-# Lazily-probed vectorized round bodies (repro.kernels.vector_rounds).
-# The probe is deferred so environments without jax (the numpy-only CI
-# legs) never pay — or fail — the kernels import; every ``vector_apply``
-# then simply reports "no vector path" and combiners run the per-op
-# loop.
-_VR: Any = None
-
 
 def _vector():
-    global _VR
-    if _VR is None:
-        try:
-            from ..kernels import vector_rounds
-            _VR = vector_rounds if vector_rounds.available() else False
-        except Exception:
-            _VR = False
-    return _VR or None
+    """The vectorized round bodies (repro.kernels.vector_rounds),
+    imported on the first ``vector_apply`` so that runs which never
+    vectorize do not import jax.  A failed import raises."""
+    from ..kernels import vector_rounds
+    return vector_rounds
 
 
 class SeqObject:
@@ -93,10 +83,9 @@ class AtomicFloatObject(SeqObject):
         return v
 
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
-        vr = _vector()
-        if vr is None or func != "MUL":
+        if func != "MUL":
             return None
-        out = vr.mul_round(nvm.read(st_base), args_list)
+        out = _vector().mul_round(nvm.read(st_base), args_list)
         if out is None:
             return None
         v, resps = out
@@ -119,10 +108,9 @@ class FetchAddObject(SeqObject):
         return v
 
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
-        vr = _vector()
-        if vr is None or func != "FAA":
+        if func != "FAA":
             return None
-        out = vr.faa_round(nvm.read(st_base), args_list)
+        out = _vector().faa_round(nvm.read(st_base), args_list)
         if out is None:
             return None
         v, resps = out
@@ -164,14 +152,13 @@ class SeqQueueObject(SeqObject):
         raise ValueError(f"unknown queue op {func}")
 
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
-        vr = _vector()
-        if vr is None or func not in ("ENQ", "DEQ"):
+        if func not in ("ENQ", "DEQ"):
             return None
         head, tail = nvm.read(st_base), nvm.read(st_base + 1)
         if type(head) is not int or type(tail) is not int:
             return None
         ring = nvm.read_range(st_base + 2, self.capacity)
-        out = vr.queue_round(ring, head, tail, func, args_list)
+        out = _vector().queue_round(ring, head, tail, func, args_list)
         if out is None:
             return None
         ring2, h2, t2, resps = out
@@ -231,14 +218,13 @@ class SeqStackObject(SeqObject):
         raise ValueError(f"unknown stack op {func}")
 
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
-        vr = _vector()
-        if vr is None or func not in ("PUSH", "POP"):
+        if func not in ("PUSH", "POP"):
             return None
         size = nvm.read(st_base)
         if type(size) is not int:
             return None
         arr = nvm.read_range(st_base + 1, self.capacity)
-        out = vr.stack_round(arr, size, func, args_list)
+        out = _vector().stack_round(arr, size, func, args_list)
         if out is None:
             return None
         arr2, s2, resps = out
@@ -322,13 +308,12 @@ class ResponseLogObject(SeqObject):
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
         # KV/log record batches: d RECORDs scatter-scanned in one kernel
         # (RECORD_MANY batches are tuples-of-tuples — eager path).
-        vr = _vector()
-        if vr is None or func != "RECORD":
+        if func != "RECORD":
             return None
         if not all(isinstance(t, (tuple, list)) and len(t) == 3
                    for t in args_list):
             return None
-        out = vr.log_round(self.n_clients, args_list)
+        out = _vector().log_round(self.n_clients, args_list)
         if out is None:
             return None
         writes, resps = out
@@ -389,13 +374,12 @@ class CheckpointObject(SeqObject):
         raise ValueError(f"unknown checkpoint op {func}")
 
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
-        vr = _vector()
-        if vr is None or func != "CKPT":
+        if func != "CKPT":
             return None
         if not all(isinstance(t, (tuple, list)) and len(t) == 2
                    for t in args_list):
             return None
-        out = vr.ckpt_round(nvm.read(st_base), args_list)
+        out = _vector().ckpt_round(nvm.read(st_base), args_list)
         if out is None:
             return None
         st, pl, resps = out
@@ -490,14 +474,13 @@ class HeapObject(SeqObject):
         # heap key-array ops: a homogeneous HINSERT/HDELETEMIN round is
         # one lax.scan over the announcements, each step sifting via a
         # lax.while_loop on the packed key array
-        vr = _vector()
-        if vr is None or func not in ("HINSERT", "HDELETEMIN"):
+        if func not in ("HINSERT", "HDELETEMIN"):
             return None
         size = nvm.read(st_base)
         if type(size) is not int:
             return None
         arr = nvm.read_range(st_base + 1, self.capacity)
-        out = vr.heap_round(arr, size, func, args_list)
+        out = _vector().heap_round(arr, size, func, args_list)
         if out is None:
             return None
         arr2, size2, resps = out

@@ -89,7 +89,7 @@ class PBComb:
         # VectorApply (DESIGN.md §11): when enabled, a combining pass
         # collects its adoptable announcements first and a homogeneous
         # batch executes as ONE jitted kernel (obj.vector_apply); any
-        # decline — mixed funcs, rich payloads, no jax — falls back to
+        # decline — mixed funcs, rich payloads, an inexact platform — runs
         # the identical per-op loop.  Off by default: the gated modeled
         # trajectory is produced with the eager path, and the
         # equivalence property tests are what license turning this on.

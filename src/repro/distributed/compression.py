@@ -20,7 +20,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 CHUNK = 1024
@@ -76,4 +76,4 @@ def compressed_psum(x: jnp.ndarray, error: jnp.ndarray, mesh: Mesh,
 
     spec = P()
     return shard_map(f, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec), check_rep=False)(x, error)
+                     out_specs=(spec, spec), check_vma=False)(x, error)

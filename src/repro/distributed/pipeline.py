@@ -14,7 +14,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -62,6 +62,6 @@ def pipeline_apply(block_fn: Callable, stage_weights, x, mesh: Mesh,
 
     f = shard_map(stage_fn, mesh=mesh,
                   in_specs=(P("stage"), P()),
-                  out_specs=P(), check_rep=False)
+                  out_specs=P(), check_vma=False)
     outs = f(stage_weights, xr)
     return outs.reshape(B, *x.shape[1:])

@@ -19,9 +19,10 @@ into an exactly repeating pattern.  This module exploits that:
      per-chunk NVM-counter deltas;
   4. replay the remaining ``k`` whole periods as arithmetic on the tape
      — a jitted f64 ``lax.scan`` over the period's event array inside a
-     ``fori_loop`` over periods (pure-Python fallback when jax is
-     absent) — then write the final clocks / device horizon / counters
-     back and run any remainder rounds eagerly.
+     ``fori_loop`` over periods (plain Python arithmetic on a TPU
+     backend, whose float64 is not IEEE binary64) — then write the
+     final clocks / device horizon / counters back and run any
+     remainder rounds eagerly.
 
 Exactness contract: the replay performs the *identical* IEEE-754 double
 operations, in the identical order, that the eager simulator would have
@@ -41,6 +42,8 @@ while workers run.
 from __future__ import annotations
 
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from . import vector_rounds
 
 __all__ = ["TapedTime", "ClockTape", "periodic_run"]
 
@@ -152,21 +155,11 @@ def _replay_python(times: List[float], device: float, ring: List[float],
 _SCAN_CACHE: Dict[Tuple[int, int, int], Any] = {}
 
 
-def _jx():
-    try:
-        from . import vector_rounds
-        if not vector_rounds.available():
-            return None
-        return vector_rounds._jx()
-    except Exception:
-        return None
-
-
-def _replay_jax(jx, times, device, ring, nc, events, k):
-    jax, jnp, lax, x64 = jx
+def _replay_jax(times, device, ring, nc, events, k):
+    jax, jnp, lax = vector_rounds._jx()
     E, R, nlid = len(events), len(ring), len(times)
 
-    with x64():
+    with jax.enable_x64(True):
         fn = _SCAN_CACHE.get((E, R, nlid))
         if fn is None:
             def run(T, D, ring, nc, kinds, lids, vals, srcs, k):
@@ -217,9 +210,11 @@ def periodic_run(nvm, round_fn: Callable[[int], None], total_rounds: int,
     """Run ``round_fn(r)`` for ``r in range(total_rounds)``, replaying
     the periodic middle through the tape engine when it verifies.
 
-    Returns an info dict: ``engine`` is ``"scan"`` / ``"python"`` when
-    periods were replayed (jax jitted vs pure-python arithmetic) or
-    ``"eager"`` with a ``reason`` when every round ran the simulator.
+    Returns an info dict: ``engine`` is ``"scan"`` when periods were
+    replayed through the jitted scan, ``"python"`` when they were
+    replayed in plain Python (a TPU backend, or periods that moved
+    counters but no clock), or ``"eager"`` with a ``reason`` when every
+    round ran the simulator.
     The NVM's modeled counters and virtual clocks end byte-identical to
     an all-eager run either way.
     """
@@ -278,15 +273,15 @@ def periodic_run(nvm, round_fn: Callable[[int], None], total_rounds: int,
             ring[(nc - min(R, len(tape.now_vals)) + j) % R] = v
         keys = list(tape._lids)
         times = [float(clk._times.get(key, 0.0)) for key in keys]
-        jx = _jx()
-        if jx is not None:
-            times, device = _replay_jax(jx, times, clk._device_free,
-                                        ring, nc, events, k)
-            engine = "scan"
-        else:
+        if vector_rounds._jx()[0].default_backend() == "tpu":
+            # float64 on the TPU is a float32 pair, not IEEE binary64
             times, device = _replay_python(times, clk._device_free,
                                            ring, nc, events, k)
             engine = "python"
+        else:
+            times, device = _replay_jax(times, clk._device_free,
+                                        ring, nc, events, k)
+            engine = "scan"
         for key, t in zip(keys, times):
             clk._times[key] = t
         clk._device_free = device

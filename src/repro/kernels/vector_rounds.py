@@ -15,8 +15,8 @@ Contract with ``SeqObject.vector_apply`` (core/objects.py):
 
   * Exactness: a kernel must produce byte-identical state words and
     responses to the per-op Python loop, or the caller must fall back.
-    Kernels therefore run in 64-bit (``jax.experimental.enable_x64``
-    scoped to this module's calls — the model substrate stays f32) and
+    Kernels therefore run in 64-bit (``jax.enable_x64(True)`` scoped
+    to this module's calls — the model substrate stays f32) and
     the packing guards reject anything that is not a plain Python int
     (or float, for the AtomicFloat kernel): rich payloads, huge ints,
     None — all take the eager path.  One documented wrinkle: ``bool``
@@ -28,9 +28,11 @@ Contract with ``SeqObject.vector_apply`` (core/objects.py):
     accessors that cost zero persistence instructions and zero modeled
     time, so the round's persistence sentence (and the gated modeled
     trajectory) is untouched by vectorization.
-  * Availability is gated: no jax in the environment means
-    ``available()`` is False and every entry returns None (callers
-    fall back to the per-op loop).
+  * Declines are semantic or per-platform: an entry returns None for a
+    batch it cannot pack (see above), or for a kernel that is not exact
+    on the default backend (``float.MUL`` on ``tpu``, DESIGN.md §11),
+    never because the device path is missing.  JAX is a core
+    dependency, so a failed import or compile raises.
 
 Kernels are cached in ``_KERNELS`` keyed by kind+op name; ``jax.jit``'s
 own cache handles shape/dtype retraces (batch size d and state width
@@ -39,40 +41,51 @@ vary per instance).
 
 from __future__ import annotations
 
+import os
+from collections import Counter
 from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-_JAX = None          # None = not probed, False = unavailable, tuple = ok
 _KERNELS: dict = {}
+#: jitted-round invocations per platform of the device that held the
+#: kernel's output ("cpu", "tpu", ...)
+_CALLS: Counter = Counter()
 
 
 def _jx():
-    global _JAX
-    if _JAX is None:
-        try:
-            import jax
-            import jax.numpy as jnp
-            from jax import lax
-            from jax.experimental import enable_x64
-            _JAX = (jax, jnp, lax, enable_x64)
-        except Exception:            # pragma: no cover - env without jax
-            _JAX = False
-    return _JAX
+    """(jax, jnp, lax), imported on the first vectorized round so that
+    importing the combining core does not pay for jax.  Kernels trace and
+    dispatch under ``jax.enable_x64(True)``."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    return jax, jnp, lax
 
 
-def available() -> bool:
-    """True when the jitted round bodies can run (jax importable)."""
-    return bool(_jx())
+def use_compile_cache(default_dir: "os.PathLike[str] | str") -> str:
+    """Keep jax's persistent compilation cache in the directory that
+    ``JAX_COMPILATION_CACHE_DIR`` names or, where that is unset, in
+    ``default_dir`` (give a fixed path: the path is part of the cache's
+    key).  Every compile is cached, the sub-second round bodies too.
+    Call it from an entry point before the first kernel, never at
+    import.  Returns the directory in use."""
+    jax = _jx()[0]
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.fspath(default_dir)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
-def kernel_calls() -> int:
-    """Total jitted-round invocations so far (tests assert the vector
-    path actually engaged rather than silently falling back)."""
-    return _CALLS[0]
-
-
-_CALLS = [0]
+def kernel_calls(platform: Optional[str] = None) -> int:
+    """Jitted-round invocations so far, on every platform or on the one
+    named.  Tests assert the vector path actually engaged rather than
+    declined; the chip smoke asserts the rounds ran on the TPU."""
+    if platform is None:
+        return sum(_CALLS.values())
+    return _CALLS[platform]
 
 
 # ------------------------------------------------------------------ #
@@ -114,27 +127,28 @@ def pack_state_f64(words: Sequence[Any]) -> Optional[np.ndarray]:
 # ------------------------------------------------------------------ #
 # kernel builders (pure functions of packed arrays)                  #
 # ------------------------------------------------------------------ #
-def _build(name: str, builder):
+def kernel(name: str):
+    """The jitted round body named ``kind.FUNC`` (a key of
+    ``BUILDERS``), built once.  Trace, lower and call it under
+    ``jax.enable_x64(True)``."""
     fn = _KERNELS.get(name)
     if fn is None:
-        jax, jnp, lax, x64 = _jx()
-        with x64():
-            fn = jax.jit(builder(jnp, lax))
+        jax, jnp, lax = _jx()
+        with jax.enable_x64(True):
+            fn = jax.jit(BUILDERS[name](jnp, lax))
         _KERNELS[name] = fn
     return fn
 
 
-def _run(name: str, builder, *args):
+def _run(name: str, *args):
     """Invoke a cached kernel under the x64 scope (dispatch must see the
     same dtypes tracing saw) and return numpy results."""
-    jx = _jx()
-    if not jx:
-        return None
-    _jax, _jnp, _lax, x64 = jx
-    fn = _build(name, builder)
-    with x64():
+    jax = _jx()[0]
+    fn = kernel(name)
+    with jax.enable_x64(True):
         out = fn(*args)
-    _CALLS[0] += 1
+    (device,) = out[0].devices()
+    _CALLS[device.platform] += 1
     return tuple(np.asarray(o) for o in out)
 
 
@@ -328,6 +342,21 @@ def _ckpt_builder(jnp, lax):
     return k
 
 
+#: every round body, keyed ``kind.FUNC``
+BUILDERS = {
+    "counter.FAA": _faa_builder,
+    "float.MUL": _mul_builder,
+    "heap.HINSERT": _heap_insert_builder,
+    "heap.HDELETEMIN": _heap_delete_builder,
+    "queue.ENQ": _queue_builder(True),
+    "queue.DEQ": _queue_builder(False),
+    "stack.PUSH": _stack_builder(True),
+    "stack.POP": _stack_builder(False),
+    "log.RECORD": _log_builder,
+    "ckpt.CKPT": _ckpt_builder,
+}
+
+
 # ------------------------------------------------------------------ #
 # per-structure entry points (numpy in, numpy out, None = fall back) #
 # ------------------------------------------------------------------ #
@@ -337,23 +366,19 @@ def faa_round(value: Any, deltas: Sequence[Any]):
     xs = pack_ints(deltas)
     if xs is None:
         return None
-    out = _run("counter.FAA", _faa_builder, np.int64(value), xs)
-    if out is None:
-        return None
-    v, outs = out
+    v, outs = _run("counter.FAA", np.int64(value), xs)
     return int(v), outs.tolist()
 
 
 def mul_round(value: Any, factors: Sequence[Any]):
-    if type(value) is not float:
+    if type(value) is not float or _jx()[0].default_backend() == "tpu":
+        # the TPU carries float64 as a pair of float32s, whose products
+        # differ from IEEE binary64 in the last bits (DESIGN.md §11)
         return None
     xs = pack_floats(factors)
     if xs is None:
         return None
-    out = _run("float.MUL", _mul_builder, np.float64(value), xs)
-    if out is None:
-        return None
-    v, outs = out
+    v, outs = _run("float.MUL", np.float64(value), xs)
     return float(v), outs.tolist()
 
 
@@ -370,19 +395,12 @@ def heap_round(arr_words: Sequence[Any], size: Any, func: str,
         xs = pack_ints(args)
         if xs is None:
             return None
-        out = _run("heap.HINSERT", _heap_insert_builder,
-                   arr, np.int64(size), xs)
-        if out is None:
-            return None
-        arr2, size2, ok = out
+        arr2, size2, ok = _run("heap.HINSERT", arr, np.int64(size), xs)
         return arr2.tolist(), int(size2), [bool(o) for o in ok]
     if func == "HDELETEMIN":
         xs = np.zeros(len(args), dtype=np.int64)
-        out = _run("heap.HDELETEMIN", _heap_delete_builder,
-                   arr, np.int64(size), xs)
-        if out is None:
-            return None
-        arr2, size2, tops, ok = out
+        arr2, size2, tops, ok = _run(
+            "heap.HDELETEMIN", arr, np.int64(size), xs)
         resps = [int(t) if o else None for t, o in zip(tops, ok)]
         return arr2.tolist(), int(size2), resps
     return None
@@ -399,20 +417,14 @@ def queue_round(ring_words: Sequence[Any], head: Any, tail: Any,
         xs = pack_ints(args)
         if xs is None:
             return None
-        out = _run("queue.ENQ", _queue_builder(True),
-                   arr, np.int64(head), np.int64(tail), xs)
-        if out is None:
-            return None
-        arr2, h2, t2, ok = out
+        arr2, h2, t2, ok = _run(
+            "queue.ENQ", arr, np.int64(head), np.int64(tail), xs)
         resps: List[Any] = ["ACK" if o else False for o in ok]
         return arr2.tolist(), int(h2), int(t2), resps
     if func == "DEQ":
         xs = np.zeros(len(args), dtype=np.int64)
-        out = _run("queue.DEQ", _queue_builder(False),
-                   arr, np.int64(head), np.int64(tail), xs)
-        if out is None:
-            return None
-        arr2, h2, t2, vals, ok = out
+        arr2, h2, t2, vals, ok = _run(
+            "queue.DEQ", arr, np.int64(head), np.int64(tail), xs)
         resps = [int(v) if o else None for v, o in zip(vals, ok)]
         return arr2.tolist(), int(h2), int(t2), resps
     return None
@@ -429,20 +441,12 @@ def stack_round(arr_words: Sequence[Any], size: Any, func: str,
         xs = pack_ints(args)
         if xs is None:
             return None
-        out = _run("stack.PUSH", _stack_builder(True),
-                   arr, np.int64(size), xs)
-        if out is None:
-            return None
-        arr2, s2, ok = out
+        arr2, s2, ok = _run("stack.PUSH", arr, np.int64(size), xs)
         resps: List[Any] = ["ACK" if o else False for o in ok]
         return arr2.tolist(), int(s2), resps
     if func == "POP":
         xs = np.zeros(len(args), dtype=np.int64)
-        out = _run("stack.POP", _stack_builder(False),
-                   arr, np.int64(size), xs)
-        if out is None:
-            return None
-        arr2, s2, vals, ok = out
+        arr2, s2, vals, ok = _run("stack.POP", arr, np.int64(size), xs)
         resps = [int(v) if o else None for v, o in zip(vals, ok)]
         return arr2.tolist(), int(s2), resps
     return None
@@ -461,10 +465,8 @@ def log_round(n_clients: int, triples: Sequence[Tuple[Any, Any, Any]]):
     if len(cs) and (cs.min() < 0 or cs.max() >= n_clients):
         return None                      # eager path raises — keep it
     zero = np.zeros(n_clients, dtype=np.int64)
-    out = _run("log.RECORD", _log_builder, zero, zero, zero, cs, ss, rs)
-    if out is None:
-        return None
-    seqs, resps, touched, outs = out
+    seqs, resps, touched, outs = _run(
+        "log.RECORD", zero, zero, zero, cs, ss, rs)
     writes = [(c, int(seqs[c]), int(resps[c]))
               for c in range(n_clients) if touched[c]]
     return writes, outs.tolist()
@@ -479,9 +481,6 @@ def ckpt_round(step: Any, pairs: Sequence[Tuple[Any, Any]]):
     ps = pack_ints([p[1] for p in pairs])
     if ss is None or ps is None:
         return None
-    out = _run("ckpt.CKPT", _ckpt_builder, np.int64(step), ss, ps)
-    if out is None:
-        return None
-    st, pl, advanced, outs = out
+    st, pl, advanced, outs = _run("ckpt.CKPT", np.int64(step), ss, ps)
     return int(st), (int(pl) if advanced else None), \
         [int(o) for o in outs]

@@ -110,7 +110,7 @@ def moe_ffn_ep(params, x, cfg, mesh):
       * one psum over 'model' recombines expert outputs — the same
         collective shape as a Megatron MLP.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
@@ -168,7 +168,7 @@ def moe_ffn_ep(params, x, cfg, mesh):
         f, mesh=mesh,
         in_specs=(P(axes, None, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
-        out_specs=P(axes, None, None), check_rep=False)
+        out_specs=P(axes, None, None), check_vma=False)
     return fn(x, params["router"], params["w_gate"], params["w_up"],
               params["w_down"])
 

@@ -117,8 +117,7 @@ def test_vector_rounds_rows(bench_doc):
             assert r["vector_apply"] is None, r
     rows = [r for r in bench_doc["rows"]
             if r["name"].startswith("vector_rounds/")]
-    if not rows:
-        pytest.skip("jax unavailable: vector_rounds emitted no rows")
+    assert rows
     names = {r["name"] for r in rows}
     for r in rows:
         _table, kind, d, side = r["name"].split("/")

@@ -18,7 +18,7 @@ import pytest
 from benchmarks import modeled
 from repro.api import registry
 from repro.core import NVM
-from repro.kernels import scan_replay, vector_rounds
+from repro.kernels import scan_replay
 from repro.kernels.scan_replay import (ClockTape, _next_pow2,
                                        _replay_python, periodic_run)
 
@@ -39,9 +39,7 @@ def test_scan_replay_byte_identical_to_eager(kind, protocol):
         assert scan[key] == eager[key], (key, scan[key], eager[key])
     # the steady state of an allocation-free cell verifies: periods
     # were actually replayed, not eagerly simulated under a new name
-    assert scan["replay_engine"] in ("scan", "python")
-    if vector_rounds.available():
-        assert scan["replay_engine"] == "scan"
+    assert scan["replay_engine"] == "scan"
 
 
 def test_engine_auto_split():
@@ -122,7 +120,23 @@ def test_synthetic_periodic_replay_exact(rounds):
     assert nvm.clock._device_free == ref.clock._device_free
 
 
-@pytest.mark.skipif(not vector_rounds.available(), reason="no jax")
+def test_tpu_backend_replays_in_python(monkeypatch):
+    """A TPU backend's float64 is a float32 pair: the replay stays in
+    plain Python there and is still byte-identical to the eager run."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rounds = 1000
+    nvm = NVM(1 << 12, profile="optane")
+    info = periodic_run(nvm, lambda r: _persist_round(nvm, r, 4), rounds)
+    assert info["engine"] == "python"
+    assert info["replayed_periods"] > 0
+    ref = NVM(1 << 12, profile="optane")
+    for r in range(rounds):
+        _persist_round(ref, r, 4)
+    assert dict(nvm.counters) == dict(ref.counters)
+    assert nvm.clock.max_time_ns() == ref.clock.max_time_ns()
+
+
 def test_replay_jax_matches_python_reference():
     """The jitted fori/scan replay computes exactly what the pure-python
     arithmetic reference does on a synthetic event tape."""
@@ -137,8 +151,7 @@ def test_replay_jax_matches_python_reference():
     k = 57
     py_t, py_d = _replay_python(list(times0), device0, list(ring0), nc0,
                                 events, k)
-    jx = scan_replay._jx()
-    jx_t, jx_d = scan_replay._replay_jax(jx, list(times0), device0,
+    jx_t, jx_d = scan_replay._replay_jax(list(times0), device0,
                                          list(ring0), nc0, events, k)
     assert jx_t == py_t
     assert jx_d == py_d

@@ -270,6 +270,39 @@ def test_spawn_workers_checks_real_substrate_not_kwarg():
         nvm.close()
 
 
+def test_vector_apply_refused_on_shm_backend():
+    """Shm rounds run in forked workers, which cannot own the chip: the
+    device path is refused there rather than left to fail in a child."""
+    rt = CombiningRuntime(n_threads=2, backend="shm")
+    try:
+        with pytest.raises(ValueError, match="threads backend"):
+            rt.make("counter", "pbcomb", vector_apply=True)
+        assert rt.objects == {}
+    finally:
+        rt.close()
+
+
+def test_spawn_workers_refuses_fork_after_accelerator_init(monkeypatch):
+    """Once the parent has initialised a non-CPU JAX backend, forking
+    workers is refused before any child starts."""
+    import jax  # noqa: F401  (the guard only looks once jax is loaded)
+    from jax._src import xla_bridge
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized",
+                        lambda: True)
+    monkeypatch.setattr(xla_bridge, "backends",
+                        lambda: {"cpu": None, "tpu": None})
+    rt = CombiningRuntime(n_threads=2, backend="shm")
+    try:
+        rt.make("queue", "pbcomb")
+        with pytest.raises(RuntimeError, match="tpu"):
+            rt.spawn_workers(2)
+        monkeypatch.setattr(xla_bridge, "backends", lambda: {"cpu": None})
+        with rt.spawn_workers(2) as pool:     # a CPU backend forks fine
+            assert len(pool.tids) == 2
+    finally:
+        rt.close()
+
+
 def test_run_ops_explicit_programs():
     rt = CombiningRuntime(n_threads=2, backend="shm")
     try:

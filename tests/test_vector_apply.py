@@ -89,10 +89,9 @@ def test_vector_equals_eager(kind, protocol):
     assert _typed(v_rets) == _typed(e_rets)
     assert v_snap == e_snap
     assert v_counters == e_counters          # modeled trajectory pinned
-    if vector_rounds.available():
-        # every round is homogeneous and int-valued: the kernel must
-        # have served them (equivalence is not decline-vs-decline)
-        assert engaged >= ROUNDS
+    # every round is homogeneous and int-valued: the kernel must have
+    # served them (equivalence is not decline-vs-decline)
+    assert engaged >= ROUNDS
 
 
 @pytest.mark.parametrize("protocol", ["pbcomb", "pwfcomb"])
@@ -144,7 +143,6 @@ def test_unpackable_payloads_decline():
     assert heap.vector_apply(nvm, hbase, "HINSERT", ["x"]) is None
 
 
-@pytest.mark.skipif(not vector_rounds.available(), reason="no jax")
 def test_bool_packs_as_int():
     """The documented wrinkle: bool is an int subclass and packs as its
     int value — the batch result must still equal the eager loop."""
@@ -158,7 +156,6 @@ def test_bool_packs_as_int():
     assert nvm.read(base) == 4
 
 
-@pytest.mark.skipif(not vector_rounds.available(), reason="no jax")
 def test_atomicfloat_mul_round_exact():
     """The paper's AtomicFloat under the seam: the scan kernel performs
     the identical float multiplies in the identical order, so state and
@@ -173,6 +170,23 @@ def test_atomicfloat_mul_round_exact():
     resps_e = [obj.apply(nvm_e, be, "MUL", a) for a in args]
     assert resps_v == resps_e
     assert nvm_v.read(bv) == nvm_e.read(be)
+
+
+def test_atomicfloat_mul_declines_on_tpu(monkeypatch):
+    """The TPU's float64 is a float32 pair, not IEEE binary64: the MUL
+    round declines there (eager loop) while the int64 kernels serve."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    nvm = NVM(1 << 10)
+    obj = AtomicFloatObject()
+    base = nvm.alloc(obj.state_words)
+    obj.init_state(nvm, base)
+    assert obj.vector_apply(nvm, base, "MUL", [1.5, 0.25]) is None
+    assert nvm.read(base) == 1.0
+    ctr = FetchAddObject()
+    cbase = nvm.alloc(ctr.state_words)
+    ctr.init_state(nvm, cbase)
+    assert ctr.vector_apply(nvm, cbase, "FAA", [2, 3]) == [0, 2]
 
 
 # --------------------------------------------------------------------- #
