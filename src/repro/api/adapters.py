@@ -209,9 +209,16 @@ class _CombiningAdapter(StructureAdapter):
                      for op in self.OPS}.values())
 
     def degree_stats(self, core):
+        """The degree counters, with PBComb's ``trace_counters`` summed
+        over its instances (waiter polls, queueing time while traced)."""
         from ..core.backend import merge_degree_stats
-        return merge_degree_stats(
-            [inst.stats.snapshot() for inst in self._instances(core)])
+        insts = self._instances(core)
+        out = merge_degree_stats([inst.stats.snapshot() for inst in insts])
+        for inst in insts:
+            if isinstance(inst, PBComb):
+                for k, v in inst.trace_counters().items():
+                    out[k] = out.get(k, 0) + v
+        return out
 
     def reset_degree_stats(self, core):
         for inst in self._instances(core):

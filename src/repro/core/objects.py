@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Tuple
 
 from .nvm import NVM
+from .tracing import span
 
 
 def _vector():
@@ -85,11 +86,14 @@ class AtomicFloatObject(SeqObject):
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
         if func != "MUL":
             return None
-        out = _vector().mul_round(nvm.read(st_base), args_list)
+        with span("seam.gather"):
+            v = nvm.read(st_base)
+        out = _vector().mul_round(v, args_list)
         if out is None:
             return None
         v, resps = out
-        nvm.write(st_base, v)
+        with span("seam.scatter"):
+            nvm.write(st_base, v)
         return resps
 
 
@@ -110,11 +114,14 @@ class FetchAddObject(SeqObject):
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
         if func != "FAA":
             return None
-        out = _vector().faa_round(nvm.read(st_base), args_list)
+        with span("seam.gather"):
+            v = nvm.read(st_base)
+        out = _vector().faa_round(v, args_list)
         if out is None:
             return None
         v, resps = out
-        nvm.write(st_base, v)
+        with span("seam.scatter"):
+            nvm.write(st_base, v)
         return resps
 
 
@@ -154,17 +161,19 @@ class SeqQueueObject(SeqObject):
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
         if func not in ("ENQ", "DEQ"):
             return None
-        head, tail = nvm.read(st_base), nvm.read(st_base + 1)
-        if type(head) is not int or type(tail) is not int:
-            return None
-        ring = nvm.read_range(st_base + 2, self.capacity)
+        with span("seam.gather"):
+            head, tail = nvm.read(st_base), nvm.read(st_base + 1)
+            if type(head) is not int or type(tail) is not int:
+                return None
+            ring = nvm.read_range(st_base + 2, self.capacity)
         out = _vector().queue_round(ring, head, tail, func, args_list)
         if out is None:
             return None
         ring2, h2, t2, resps = out
-        nvm.write(st_base, h2)
-        nvm.write(st_base + 1, t2)
-        nvm.write_range(st_base + 2, ring2)
+        with span("seam.scatter"):
+            nvm.write(st_base, h2)
+            nvm.write(st_base + 1, t2)
+            nvm.write_range(st_base + 2, ring2)
         return resps
 
     def touch_plan(self, nvm: NVM, st_base: int, func: str,
@@ -220,16 +229,18 @@ class SeqStackObject(SeqObject):
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
         if func not in ("PUSH", "POP"):
             return None
-        size = nvm.read(st_base)
-        if type(size) is not int:
-            return None
-        arr = nvm.read_range(st_base + 1, self.capacity)
+        with span("seam.gather"):
+            size = nvm.read(st_base)
+            if type(size) is not int:
+                return None
+            arr = nvm.read_range(st_base + 1, self.capacity)
         out = _vector().stack_round(arr, size, func, args_list)
         if out is None:
             return None
         arr2, s2, resps = out
-        nvm.write(st_base, s2)
-        nvm.write_range(st_base + 1, arr2)
+        with span("seam.scatter"):
+            nvm.write(st_base, s2)
+            nvm.write_range(st_base + 1, arr2)
         return resps
 
     def touch_plan(self, nvm: NVM, st_base: int, func: str,
@@ -317,11 +328,12 @@ class ResponseLogObject(SeqObject):
         if out is None:
             return None
         writes, resps = out
-        for client, seq, resp in writes:
-            # response before seq — same torn-StateRec discipline as
-            # the eager ``_record``
-            nvm.write(st_base + 2 * client + 1, resp)
-            nvm.write(st_base + 2 * client, seq)
+        with span("seam.scatter"):
+            for client, seq, resp in writes:
+                # response before seq — same torn-StateRec discipline as
+                # the eager ``_record``
+                nvm.write(st_base + 2 * client + 1, resp)
+                nvm.write(st_base + 2 * client, seq)
         return resps
 
     def touch_plan(self, nvm: NVM, st_base: int, func: str,
@@ -379,14 +391,17 @@ class CheckpointObject(SeqObject):
         if not all(isinstance(t, (tuple, list)) and len(t) == 2
                    for t in args_list):
             return None
-        out = _vector().ckpt_round(nvm.read(st_base), args_list)
+        with span("seam.gather"):
+            step = nvm.read(st_base)
+        out = _vector().ckpt_round(step, args_list)
         if out is None:
             return None
         st, pl, resps = out
         if pl is not None:       # some element advanced the step
-            # payload before step — same torn-StateRec discipline
-            nvm.write(st_base + 1, pl)
-            nvm.write(st_base, st)
+            with span("seam.scatter"):
+                # payload before step — same torn-StateRec discipline
+                nvm.write(st_base + 1, pl)
+                nvm.write(st_base, st)
         return resps
 
     def touch_plan(self, nvm: NVM, st_base: int, func: str,
@@ -476,14 +491,16 @@ class HeapObject(SeqObject):
         # lax.while_loop on the packed key array
         if func not in ("HINSERT", "HDELETEMIN"):
             return None
-        size = nvm.read(st_base)
-        if type(size) is not int:
-            return None
-        arr = nvm.read_range(st_base + 1, self.capacity)
+        with span("seam.gather"):
+            size = nvm.read(st_base)
+            if type(size) is not int:
+                return None
+            arr = nvm.read_range(st_base + 1, self.capacity)
         out = _vector().heap_round(arr, size, func, args_list)
         if out is None:
             return None
         arr2, size2, resps = out
-        nvm.write(st_base, size2)
-        nvm.write_range(st_base + 1, arr2)
+        with span("seam.scatter"):
+            nvm.write(st_base, size2)
+            nvm.write_range(st_base + 1, arr2)
         return resps

@@ -33,9 +33,11 @@ import time
 from dataclasses import dataclass
 from typing import Any, Optional
 
+from . import tracing
 from .atomics import Counters
 from .nvm import NVM, SimulatedCrash
 from .objects import SeqObject
+from .tracing import span
 
 
 @dataclass(slots=True)
@@ -59,6 +61,9 @@ class RequestRec:
     # on a mismatch; the writer's announce is then simply "not yet
     # published" for that pass.
     stamp: int = 0
+    # perf_counter_ns() of the announce, written only while tracing is
+    # on: the combiner that adopts the request counts its queueing time
+    t_ns: int = 0
 
 
 class PBComb:
@@ -143,6 +148,14 @@ class PBComb:
         # staging; mp_bench and the matrix bench report it.
         self.stats = be.degree_stats()
         self._round_served = 0
+        # Where the host time of a round goes (core/tracing.py): lock
+        # polls per waiting thread, always counted, each thread in its
+        # own slot; announce-to-adoption time and the adoptions timed,
+        # summed by combiners (under the lock) while tracing is on.
+        # Per process: shm workers' polls stay in their own copies.
+        self.waiter_polls = [0] * n_threads
+        self.queue_ns = 0
+        self.queued_ops = 0
 
     # LockVal lives in a backend cell so a combiner process's write is
     # visible to waiter processes; property keeps the paper's name.
@@ -188,6 +201,8 @@ class PBComb:
         clk = self._clock
         if clk is not None:
             req.vtime = clk.now()
+        if tracing.enabled:
+            req.t_ns = time.perf_counter_ns()
         req.valid = 1
         req.stamp = st + 1      # even: published
         if self.park_enabled and self._rng.random() < self._park_prob:
@@ -258,7 +273,7 @@ class PBComb:
     SPIN_FAST = 3
     PARK_SECONDS = 2e-5
 
-    def _wait_while(self, expected: int) -> None:
+    def _wait_while(self, p: int, expected: int) -> None:
         lock = self.lock
         nvm = self.nvm
         spins = 0
@@ -270,6 +285,7 @@ class PBComb:
                 raise SimulatedCrash()
             spins += 1
             time.sleep(0 if spins <= self.SPIN_FAST else self.PARK_SECONDS)
+        self.waiter_polls[p] += spins
 
     # ---------------- Algorithm 2 ------------------------------------- #
     def _perform_request(self, p: int) -> Any:
@@ -294,12 +310,12 @@ class PBComb:
                 if clk is not None:
                     clk.advance(clk.profile.cas_ns)
                 lval += 1                                    # line 9
-            self._wait_while(lval)                           # line 10
+            self._wait_while(p, lval)                        # line 10
             mindex = self._mindex()
             if self.request[p].activate == nvm.read(self._deact_addr(mindex, p)):  # line 11
                 if self.lockval != lval:                     # line 12
                     # Served by an in-flight round: wait for its psync.
-                    self._wait_while(lval + 2)
+                    self._wait_while(p, lval + 2)
                 if clk is not None:
                     # Lamport hand-off: the waiter's clock jumps to the
                     # serving round's commit time (max, not sum).
@@ -340,37 +356,59 @@ class PBComb:
         # each thread contributes at most one request per round (at
         # most n passes, typically 2).
         vector = self._vector_enabled
+        traced = tracing.enabled
+        if traced:
+            tracing.set_round(lock_val)
+            since = tracing.since_ns
+        queue_ns = queued = 0
+        n_pass = 0
         while True:
             pass_served = 0
             batch = [] if vector else None
-            deacts = nvm.read_range(deact_base, self.n)  # one slice, n reads
-            for q in range(self.n):                          # line 16
-                req = request[q]
-                # seqlock snapshot: skip records mid-announce, and
-                # re-check the stamp after the field reads so a mixed
-                # (func from one announce, args from the next) record
-                # is never applied — a skipped record is adopted by a
-                # later fixpoint pass or the announcer's own round
-                s1 = req.stamp
-                act = req.activate
-                if s1 & 1 or req.valid != 1 or act == deacts[q]:  # line 17
-                    continue
-                func, args, vt = req.func, req.args, req.vtime
-                if req.stamp != s1:
-                    continue
-                if PBComb.torn_announce_bug:
-                    args = self._bug_torn_args(q, args)
-                if clk is not None:
-                    clk.merge(vt)         # Lamport receive of announce
-                if batch is not None:
-                    # VectorApply: adopt now, apply the whole pass below
-                    # (merging first is clock-identical — merge is a max)
-                    batch.append((q, func, args, act))
-                    continue
-                ret = self._apply(q, func, args, ind, p)       # lines 18-19
-                wr(retval_base + q, ret)                           # line 20
-                wr(deact_base + q, act)                            # line 21
-                pass_served += 1
+            scan = span("combine.scan", **{"pass": n_pass})
+            n_pass += 1
+            with scan:
+                deacts = nvm.read_range(deact_base, self.n)  # n reads
+                for q in range(self.n):                      # line 16
+                    req = request[q]
+                    # seqlock snapshot: skip records mid-announce, and
+                    # re-check the stamp after the field reads so a
+                    # mixed (func from one announce, args from the next)
+                    # record is never applied — a skipped record is
+                    # adopted by a later fixpoint pass or the
+                    # announcer's own round
+                    s1 = req.stamp
+                    act = req.activate
+                    if (s1 & 1 or req.valid != 1
+                            or act == deacts[q]):                 # line 17
+                        continue
+                    func, args, vt = req.func, req.args, req.vtime
+                    t_ns = req.t_ns if traced else 0
+                    if req.stamp != s1:
+                        continue
+                    if traced and t_ns >= since:
+                        queue_ns += time.perf_counter_ns() - t_ns
+                        queued += 1
+                    if PBComb.torn_announce_bug:
+                        args = self._bug_torn_args(q, args)
+                    if clk is not None:
+                        clk.merge(vt)         # Lamport receive of announce
+                    if batch is not None:
+                        # VectorApply: adopt now, apply the whole pass
+                        # below (merging first is clock-identical —
+                        # merge is a max)
+                        batch.append((q, func, args, act))
+                        continue
+                    if traced:                               # lines 18-19
+                        with span("combine.host_apply"):
+                            ret = self._apply(q, func, args, ind, p)
+                    else:
+                        ret = self._apply(q, func, args, ind, p)
+                    wr(retval_base + q, ret)                     # line 20
+                    wr(deact_base + q, act)                      # line 21
+                    pass_served += 1
+                scan.set_metadata(adopted=len(batch) if vector
+                                    else pass_served)
             if batch:
                 rets = self._apply_batch(batch, ind, p)
                 for (q, _f, _a, act), ret in zip(batch, rets):
@@ -389,6 +427,10 @@ class PBComb:
         # Measured degree: requests this committed round served (the
         # loop above plus any eliminated pairs _begin_round recorded).
         self.stats.record(served + self._round_served)
+        if traced:
+            self.queue_ns += queue_ns
+            self.queued_ops += queued
+            tracing.set_round(None)
         if clk is not None:
             self._round_end_vt = clk.now()   # published before the unlock
         self._pre_unlock(ind, p)
@@ -436,8 +478,16 @@ class PBComb:
                 [b[2] for b in batch], ctx=self)
             if rets is not None:
                 return rets
-        return [self._apply(q, f, a, ind, combiner)
-                for q, f, a, _act in batch]
+        with span("combine.host_apply"):
+            return [self._apply(q, f, a, ind, combiner)
+                    for q, f, a, _act in batch]
+
+    def trace_counters(self) -> dict:
+        """Lock polls of waiting threads, and the queueing time and
+        count of the requests adopted while tracing was on, since the
+        core was made."""
+        return {"waiter_polls": sum(self.waiter_polls),
+                "queue_ns": self.queue_ns, "queued_ops": self.queued_ops}
 
     def _begin_round(self, ind: int, combiner: int) -> None:
         """Called after the state copy, before the simulation loop.

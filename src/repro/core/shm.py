@@ -74,6 +74,7 @@ import struct
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
+from . import tracing
 from .atomics import Counters
 from .backend import ThreadBackend
 from .nvm import LINE, NVM, SimulatedCrash
@@ -788,8 +789,8 @@ class ShmIntArray:
 
 
 # Request-board field offsets (codec words per RequestRec slot).
-_RB_FUNC, _RB_ARGS, _RB_ACT, _RB_VALID, _RB_VTIME, _RB_STAMP, _RB_WORDS = \
-    0, 1, 2, 3, 4, 5, 6
+(_RB_FUNC, _RB_ARGS, _RB_ACT, _RB_VALID, _RB_VTIME, _RB_STAMP, _RB_TNS,
+ _RB_WORDS) = 0, 1, 2, 3, 4, 5, 6, 7
 
 
 class ShmRequestRec:
@@ -851,6 +852,14 @@ class ShmRequestRec:
     def stamp(self, v):
         self._w.set(self._b + _RB_STAMP, v)
 
+    @property
+    def t_ns(self):
+        return self._w.get(self._b + _RB_TNS)
+
+    @t_ns.setter
+    def t_ns(self, v):
+        self._w.set(self._b + _RB_TNS, v)
+
 
 class ShmRequestBoard(list):
     """Announcement board in shared memory: ``board[p]`` is a live view;
@@ -875,6 +884,8 @@ class ShmRequestBoard(list):
         view.args = rec.args
         view.activate = rec.activate
         view.vtime = rec.vtime
+        if tracing.enabled:         # read only while tracing is on
+            view.t_ns = rec.t_ns
         view.valid = rec.valid
         view.stamp = st + 1             # even: published
 
@@ -887,6 +898,7 @@ class ShmRequestBoard(list):
             view.args = None
             view.activate = 0
             view.vtime = 0.0
+            view.t_ns = 0
             view.stamp = st + 1
 
 

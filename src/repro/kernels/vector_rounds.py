@@ -47,6 +47,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..core.tracing import span
+
 _KERNELS: dict = {}
 #: jitted-round invocations per platform of the device that held the
 #: kernel's output ("cpu", "tpu", ...)
@@ -130,26 +132,36 @@ def pack_state_f64(words: Sequence[Any]) -> Optional[np.ndarray]:
 def kernel(name: str):
     """The jitted round body named ``kind.FUNC`` (a key of
     ``BUILDERS``), built once.  Trace, lower and call it under
-    ``jax.enable_x64(True)``."""
+    ``jax.enable_x64(True)``.  Its function is named after the key
+    (``heap_HINSERT``), so its module is ``jit_heap_HINSERT`` in a
+    profile."""
     fn = _KERNELS.get(name)
     if fn is None:
         jax, jnp, lax = _jx()
+        body = BUILDERS[name](jnp, lax)
+        body.__name__ = body.__qualname__ = name.replace(".", "_")
         with jax.enable_x64(True):
-            fn = jax.jit(BUILDERS[name](jnp, lax))
+            fn = jax.jit(body)
         _KERNELS[name] = fn
     return fn
 
 
 def _run(name: str, *args):
     """Invoke a cached kernel under the x64 scope (dispatch must see the
-    same dtypes tracing saw) and return numpy results."""
-    jax = _jx()[0]
-    fn = kernel(name)
-    with jax.enable_x64(True):
-        out = fn(*args)
-    (device,) = out[0].devices()
-    _CALLS[device.platform] += 1
-    return tuple(np.asarray(o) for o in out)
+    same dtypes tracing saw) and return numpy results.  The last
+    argument is the batch: one element per request."""
+    with span("seam.dispatch", kernel=name, d=len(args[-1])):
+        jax = _jx()[0]
+        fn = kernel(name)
+        with jax.enable_x64(True):
+            out = fn(*args)
+        (device,) = out[0].devices()
+        _CALLS[device.platform] += 1
+    with span("seam.fetch"):
+        host = tuple(np.asarray(o) for o in out)
+        # freeing the device outputs can give up the GIL as well
+        del out
+    return host
 
 
 def _faa_builder(jnp, lax):
@@ -363,11 +375,13 @@ BUILDERS = {
 def faa_round(value: Any, deltas: Sequence[Any]):
     if type(value) is not int:
         return None
-    xs = pack_ints(deltas)
+    with span("seam.gather"):
+        xs = pack_ints(deltas)
     if xs is None:
         return None
     v, outs = _run("counter.FAA", np.int64(value), xs)
-    return int(v), outs.tolist()
+    with span("seam.scatter"):
+        return int(v), outs.tolist()
 
 
 def mul_round(value: Any, factors: Sequence[Any]):
@@ -375,81 +389,80 @@ def mul_round(value: Any, factors: Sequence[Any]):
         # the TPU carries float64 as a pair of float32s, whose products
         # differ from IEEE binary64 in the last bits (DESIGN.md §11)
         return None
-    xs = pack_floats(factors)
+    with span("seam.gather"):
+        xs = pack_floats(factors)
     if xs is None:
         return None
     v, outs = _run("float.MUL", np.float64(value), xs)
-    return float(v), outs.tolist()
+    with span("seam.scatter"):
+        return float(v), outs.tolist()
 
 
 def heap_round(arr_words: Sequence[Any], size: Any, func: str,
                args: Sequence[Any]):
     """One homogeneous heap round (HINSERT or HDELETEMIN) over the full
     key array.  Returns (new_words, new_size, responses) or None."""
-    if type(size) is not int:
+    if type(size) is not int or func not in ("HINSERT", "HDELETEMIN"):
         return None
-    arr = pack_state(arr_words)
-    if arr is None:
+    with span("seam.gather"):
+        arr = pack_state(arr_words)
+        xs = (pack_ints(args) if func == "HINSERT"
+              else np.zeros(len(args), dtype=np.int64))
+    if arr is None or xs is None:
         return None
     if func == "HINSERT":
-        xs = pack_ints(args)
-        if xs is None:
-            return None
         arr2, size2, ok = _run("heap.HINSERT", arr, np.int64(size), xs)
-        return arr2.tolist(), int(size2), [bool(o) for o in ok]
-    if func == "HDELETEMIN":
-        xs = np.zeros(len(args), dtype=np.int64)
-        arr2, size2, tops, ok = _run(
-            "heap.HDELETEMIN", arr, np.int64(size), xs)
+        with span("seam.scatter"):
+            return arr2.tolist(), int(size2), [bool(o) for o in ok]
+    arr2, size2, tops, ok = _run("heap.HDELETEMIN", arr, np.int64(size), xs)
+    with span("seam.scatter"):
         resps = [int(t) if o else None for t, o in zip(tops, ok)]
         return arr2.tolist(), int(size2), resps
-    return None
 
 
 def queue_round(ring_words: Sequence[Any], head: Any, tail: Any,
                 func: str, args: Sequence[Any]):
-    if type(head) is not int or type(tail) is not int:
+    if (type(head) is not int or type(tail) is not int
+            or func not in ("ENQ", "DEQ")):
         return None
-    arr = pack_state(ring_words)
-    if arr is None:
+    with span("seam.gather"):
+        arr = pack_state(ring_words)
+        xs = (pack_ints(args) if func == "ENQ"
+              else np.zeros(len(args), dtype=np.int64))
+    if arr is None or xs is None:
         return None
     if func == "ENQ":
-        xs = pack_ints(args)
-        if xs is None:
-            return None
         arr2, h2, t2, ok = _run(
             "queue.ENQ", arr, np.int64(head), np.int64(tail), xs)
-        resps: List[Any] = ["ACK" if o else False for o in ok]
-        return arr2.tolist(), int(h2), int(t2), resps
-    if func == "DEQ":
-        xs = np.zeros(len(args), dtype=np.int64)
-        arr2, h2, t2, vals, ok = _run(
-            "queue.DEQ", arr, np.int64(head), np.int64(tail), xs)
+        with span("seam.scatter"):
+            resps: List[Any] = ["ACK" if o else False for o in ok]
+            return arr2.tolist(), int(h2), int(t2), resps
+    arr2, h2, t2, vals, ok = _run(
+        "queue.DEQ", arr, np.int64(head), np.int64(tail), xs)
+    with span("seam.scatter"):
         resps = [int(v) if o else None for v, o in zip(vals, ok)]
         return arr2.tolist(), int(h2), int(t2), resps
-    return None
 
 
 def stack_round(arr_words: Sequence[Any], size: Any, func: str,
                 args: Sequence[Any]):
-    if type(size) is not int:
+    if type(size) is not int or func not in ("PUSH", "POP"):
         return None
-    arr = pack_state(arr_words)
-    if arr is None:
+    with span("seam.gather"):
+        arr = pack_state(arr_words)
+        xs = (pack_ints(args) if func == "PUSH"
+              else np.zeros(len(args), dtype=np.int64))
+    if arr is None or xs is None:
         return None
     if func == "PUSH":
-        xs = pack_ints(args)
-        if xs is None:
-            return None
         arr2, s2, ok = _run("stack.PUSH", arr, np.int64(size), xs)
-        resps: List[Any] = ["ACK" if o else False for o in ok]
-        return arr2.tolist(), int(s2), resps
-    if func == "POP":
-        xs = np.zeros(len(args), dtype=np.int64)
-        arr2, s2, vals, ok = _run("stack.POP", arr, np.int64(size), xs)
+        with span("seam.scatter"):
+            resps: List[Any] = ["ACK" if o else False for o in ok]
+            return arr2.tolist(), int(s2), resps
+    arr2, s2, vals, ok = _run("stack.POP", arr, np.int64(size), xs)
+    with span("seam.scatter"):
         resps = [int(v) if o else None for v, o in zip(vals, ok)]
         return arr2.tolist(), int(s2), resps
-    return None
 
 
 def log_round(n_clients: int, triples: Sequence[Tuple[Any, Any, Any]]):
@@ -457,19 +470,21 @@ def log_round(n_clients: int, triples: Sequence[Tuple[Any, Any, Any]]):
     Returns ``(writes, responses)`` where writes is a list of
     ``(client, seq, resp)`` — one per client the batch touched — or
     None."""
-    cs = pack_ints([t[0] for t in triples])
-    ss = pack_ints([t[1] for t in triples])
-    rs = pack_ints([t[2] for t in triples])
+    with span("seam.gather"):
+        cs = pack_ints([t[0] for t in triples])
+        ss = pack_ints([t[1] for t in triples])
+        rs = pack_ints([t[2] for t in triples])
+        zero = np.zeros(n_clients, dtype=np.int64)
     if cs is None or ss is None or rs is None:
         return None
     if len(cs) and (cs.min() < 0 or cs.max() >= n_clients):
         return None                      # eager path raises — keep it
-    zero = np.zeros(n_clients, dtype=np.int64)
     seqs, resps, touched, outs = _run(
         "log.RECORD", zero, zero, zero, cs, ss, rs)
-    writes = [(c, int(seqs[c]), int(resps[c]))
-              for c in range(n_clients) if touched[c]]
-    return writes, outs.tolist()
+    with span("seam.scatter"):
+        writes = [(c, int(seqs[c]), int(resps[c]))
+                  for c in range(n_clients) if touched[c]]
+        return writes, outs.tolist()
 
 
 def ckpt_round(step: Any, pairs: Sequence[Tuple[Any, Any]]):
@@ -477,10 +492,12 @@ def ckpt_round(step: Any, pairs: Sequence[Tuple[Any, Any]]):
     ``(new_step, new_payload_or_None_if_unchanged, responses)``."""
     if type(step) is not int:
         return None
-    ss = pack_ints([p[0] for p in pairs])
-    ps = pack_ints([p[1] for p in pairs])
+    with span("seam.gather"):
+        ss = pack_ints([p[0] for p in pairs])
+        ps = pack_ints([p[1] for p in pairs])
     if ss is None or ps is None:
         return None
     st, pl, advanced, outs = _run("ckpt.CKPT", np.int64(step), ss, ps)
-    return int(st), (int(pl) if advanced else None), \
-        [int(o) for o in outs]
+    with span("seam.scatter"):
+        return int(st), (int(pl) if advanced else None), \
+            [int(o) for o in outs]
