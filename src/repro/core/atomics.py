@@ -97,6 +97,61 @@ class AtomicInt:
             return old
 
 
+class WaitableInt:
+    """A shared int that threads can wait on without polling: PBComb's
+    lock word on the thread backend.  A waiter blocks on a gate of its
+    own (a held ``threading.Lock``) that the next ``store`` opens; every
+    store opens every gate.  A woken waiter takes no shared lock on its
+    way out: a ``threading.Condition`` would make all of them re-take
+    its one lock at once, and under the GIL that convoy cost the
+    combiner a switch interval per hand-off.  Only load and store:
+    nothing else writes the lock.  Counted as ``AtomicInt`` counts a
+    shared word: each read of the value is a shared read, each store a
+    shared write."""
+
+    __slots__ = ("_value", "_mutex", "_gates", "_count")
+
+    def __init__(self, value: int = 0, *,
+                 counters: Optional[Counters] = None) -> None:
+        self._value = value
+        self._mutex = threading.Lock()    # guards _value writes and _gates
+        self._gates: list = []
+        self._count = counters
+
+    def load(self) -> int:
+        if self._count is not None:
+            self._count.shared_reads += 1
+        return self._value
+
+    def store(self, value: int) -> None:
+        if self._count is not None:
+            self._count.shared_writes += 1
+        with self._mutex:
+            self._value = value
+            gates, self._gates = self._gates, []
+        for gate in gates:
+            gate.release()
+
+    def wait_while(self, expected: int, nvm: Any = None) -> int:
+        """Block while the word holds ``expected``; return the waits
+        that ended (0 if it already differed).  A store cannot slip
+        between the re-check and the wait: the gate is queued under the
+        mutex the store takes.  No timeout: the in-process NVM never
+        halts (``nvm`` is unused; the shm word polls its ``halted``
+        flag instead)."""
+        waits = 0
+        while self.load() == expected:
+            gate = threading.Lock()
+            gate.acquire()
+            with self._mutex:
+                if self._value != expected:
+                    break
+                self._gates.append(gate)
+            gate.acquire()              # until a store opens the gate
+            waits += 1
+        return waits
+
+
 class AtomicRef:
     """Versioned reference supporting LL/VL/SC (ABA-safe, as in paper §6).
     Instrumentation (counters, virtual clock) opt-in as for

@@ -37,7 +37,7 @@ from __future__ import annotations
 import threading
 from typing import Any, List, Optional, Tuple
 
-from .atomics import AtomicInt, AtomicRef, Counters
+from .atomics import AtomicInt, AtomicRef, Counters, WaitableInt
 
 
 class Cell:
@@ -150,6 +150,12 @@ class ThreadBackend:
         return AtomicInt(value, shared=shared, counters=counters,
                          clock=clock)
 
+    def waitable_int(self, value: int = 0, *,
+                     counters: Optional[Counters] = None) -> WaitableInt:
+        """A shared int with ``wait_while`` (PBComb's lock word): here
+        its waiters block until a store; the shm backend's poll."""
+        return WaitableInt(value, counters=counters)
+
     def atomic_ref(self, value: Any, *, shared: bool = False,
                    counters: Optional[Counters] = None,
                    clock: Optional[Any] = None,
@@ -199,6 +205,11 @@ class ThreadBackend:
                          clock: Optional[Any] = None) -> AtomicInt:
         return AtomicInt(value, shared=shared, counters=counters,
                          clock=clock)
+
+    def reset_waitable_int(self, a: WaitableInt, value: int = 0, *,
+                           counters: Optional[Counters] = None
+                           ) -> WaitableInt:
+        return WaitableInt(value, counters=counters)
 
     def reset_atomic_ref(self, a, value: Any, *, shared: bool = False,
                          counters: Optional[Counters] = None,

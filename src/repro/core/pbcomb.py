@@ -35,7 +35,7 @@ from typing import Any, Optional
 
 from . import tracing
 from .atomics import Counters
-from .nvm import NVM, SimulatedCrash
+from .nvm import NVM
 from .objects import SeqObject
 from .tracing import span
 
@@ -130,8 +130,7 @@ class PBComb:
         # later round may overwrite it before a slow waiter reads it —
         # merge is a max, so that only ever charges the waiter MORE.
         self._round_end_vt = 0.0
-        self.lock = be.atomic_int(0, shared=True, counters=counters,
-                                  clock=nvm.clock)
+        self.lock = be.waitable_int(0, counters=counters)
         self._lockval = be.cell(0)  # written by the combiner, read by waiters
         # Combiner election (the line 8 CAS) as a non-blocking mutex
         # try-acquire: same atomicity, one C call instead of a guarded
@@ -246,10 +245,8 @@ class PBComb:
         worker processes stay attached."""
         be = self.nvm.backend
         self.request.reset()
-        self.lock = be.reset_atomic_int(self.lock, 0,
-                                        shared=True,
-                                        counters=self._counters,
-                                        clock=self.nvm.clock)
+        self.lock = be.reset_waitable_int(self.lock, 0,
+                                          counters=self._counters)
         self.lockval = 0
         self._elect = be.reset_mutex(self._elect)  # may be held at crash
         for p in range(self.n):
@@ -263,29 +260,17 @@ class PBComb:
         deact = self.nvm.read(self._deact_addr(self._mindex(), p))
         self.request[p] = RequestRec(None, None, deact, 0)
 
-    # A waiter spins a few GIL-yields, then parks on a real (tiny) sleep.
-    # On hardware the paper's waiters spin on a cache line; under CPython
-    # a pure ``sleep(0)`` spinner can convoy the GIL against the combiner
-    # (it re-wins the handoff), starving the very round that would serve
-    # it.  Parking lets the combiner run — and widens the announcement
-    # window, so rounds combine MORE requests per psync, which is the
-    # effect the protocol exists to create.
-    SPIN_FAST = 3
-    PARK_SECONDS = 2e-5
-
+    # Line 10's wait is the lock word's own (``backend.waitable_int``).
+    # On the thread backend a waiter blocks until the unlock's store
+    # opens its gate (``WaitableInt``): a poller would take the GIL on
+    # every re-check, and each time the combiner gives the GIL up (a
+    # kernel launch, a fetch) it would have to win it back against
+    # every poller, a switch interval or more per blocking step.  Shm
+    # workers are processes that share no GIL and no ``threading``
+    # object, so their word polls, and leaves on the shared ``halted``
+    # flag.
     def _wait_while(self, p: int, expected: int) -> None:
-        lock = self.lock
-        nvm = self.nvm
-        spins = 0
-        while lock.load() == expected:
-            # Machine-off check: a crash in ANOTHER process cannot unwind
-            # this one, so waiters poll the shared halted flag instead of
-            # spinning on a lock word the dead combiner never releases.
-            if nvm.halted:
-                raise SimulatedCrash()
-            spins += 1
-            time.sleep(0 if spins <= self.SPIN_FAST else self.PARK_SECONDS)
-        self.waiter_polls[p] += spins
+        self.waiter_polls[p] += self.lock.wait_while(expected, self.nvm)
 
     # ---------------- Algorithm 2 ------------------------------------- #
     def _perform_request(self, p: int) -> Any:

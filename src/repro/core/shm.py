@@ -621,6 +621,26 @@ class ShmAtomicInt:
     def reset(self, value: int = 0) -> None:
         self._mv[self._off] = value
 
+    # Waiters in other processes hold no GIL this one needs and cannot
+    # share a ``threading`` condition, so they poll: a few yields, then
+    # a tiny sleep, which also widens the announcement window.
+    SPIN_FAST = 3
+    PARK_SECONDS = 2e-5
+
+    def wait_while(self, expected: int, nvm: Any) -> int:
+        """Poll while the word holds ``expected``; return the polls.
+        Machine-off check: a crash in ANOTHER process cannot unwind
+        this one, so the loop leaves on the shared ``halted`` flag
+        instead of spinning on a lock word the dead combiner never
+        releases."""
+        spins = 0
+        while self.load() == expected:
+            if nvm.halted:
+                raise SimulatedCrash()
+            spins += 1
+            time.sleep(0 if spins <= self.SPIN_FAST else self.PARK_SECONDS)
+        return spins
+
 
 class ShmAtomicRef:
     """Versioned LL/VL/SC reference over shared memory (codec value +
@@ -1221,6 +1241,10 @@ class ShmBackend(ThreadBackend):
         return ShmAtomicInt(self, value, shared=shared, counters=counters,
                             clock=clock)
 
+    def waitable_int(self, value: int = 0, *,
+                     counters: Optional[Counters] = None) -> ShmAtomicInt:
+        return ShmAtomicInt(self, value, shared=True, counters=counters)
+
     def atomic_ref(self, value: Any, *, shared: bool = False,
                    counters: Optional[Counters] = None,
                    clock: Optional[Any] = None,
@@ -1257,6 +1281,8 @@ class ShmBackend(ThreadBackend):
                          **_kw) -> ShmAtomicInt:
         a.reset(value)
         return a
+
+    reset_waitable_int = reset_atomic_int
 
     def reset_atomic_ref(self, a: ShmAtomicRef, value: Any, *,
                          mirror: Optional[Tuple[Any, int]] = None,

@@ -2,7 +2,9 @@
 under exhaustive crash-point sweeps (paper Sections 3-4)."""
 
 import random
+import sys
 import threading
+import time
 
 import pytest
 
@@ -161,3 +163,118 @@ def test_pbcomb_combiner_crash_then_repeat_crash_in_recovery():
     ret = c.recover(0, "FAA", 1, 1)
     assert ret == 0
     assert nvm.read(c._st_base(c._mindex())) == 1
+
+
+# --------------------------------------------------------------------- #
+# Blocking waiters under a full closed loop (no lost wake-up)           #
+# --------------------------------------------------------------------- #
+STRESS_THREADS = 64
+STRESS_OPS = 200
+
+
+def _commit_log(core):
+    """The adoptions ``(client, func, args)`` of every committed round,
+    in the order the combiner applied them: a pass is logged as it
+    runs, a round at its unlock (after its psync, under the lock)."""
+    rounds, passes = [], []
+    apply_batch, pre_unlock = core._apply_batch, core._pre_unlock
+
+    def logged_batch(batch, ind, p):
+        passes.extend((q, f, a) for q, f, a, _act in batch)
+        return apply_batch(batch, ind, p)
+
+    def logged_unlock(ind, p):
+        rounds.append(passes[:])
+        passes.clear()
+        return pre_unlock(ind, p)
+    core._apply_batch, core._pre_unlock = logged_batch, logged_unlock
+    return rounds
+
+
+def _ref_counter():
+    state = {"v": 0}
+
+    def apply(func, delta):
+        assert func == "FAA"
+        old = state["v"]
+        state["v"] = old + delta
+        return old
+    return apply, lambda: state["v"]
+
+
+def _ref_heap():
+    import heapq
+    keys = []
+
+    def apply(func, key):
+        if func == "HINSERT":
+            heapq.heappush(keys, key)
+            return True
+        assert func == "HDELETEMIN"
+        return heapq.heappop(keys) if keys else None
+    return apply, lambda: sorted(keys)
+
+
+def _counter_client(b, p, i):
+    d = 1 + (p * 7 + i) % 5
+    return d, b.fetch_add(d)
+
+
+def _heap_client(b, p, i):
+    if i % 2:
+        return None, b.delete_min()
+    k = (p * 1009 + i * 31) % 4096
+    return k, b.insert(k)
+
+
+@pytest.mark.parametrize("kind", ["counter", "heap"])
+def test_blocking_waiters_lose_no_wakeup(kind):
+    """64 threads in a closed loop through the vector seam: every wait
+    ends (a lost wake-up leaves a thread blocked and fails the join),
+    each reply and the final state match a plain reference replaying
+    the order the combiners committed, and every committed round made
+    exactly one psync."""
+    from repro.api import CombiningRuntime
+    rt = CombiningRuntime(n_threads=STRESS_THREADS)
+    extra = {"capacity": 2 * STRESS_THREADS} if kind == "heap" else {}
+    obj = rt.make(kind, "pbcomb", vector_apply=True, **extra)
+    rounds = _commit_log(obj.core)
+    client = _counter_client if kind == "counter" else _heap_client
+    replies = [[] for _ in range(STRESS_THREADS)]
+
+    def run(p):
+        b = rt.attach(p).bind(obj)
+        for i in range(STRESS_OPS):
+            replies[p].append(client(b, p, i))
+    psyncs = rt.nvm.counters["psync"]
+    ts = [threading.Thread(target=run, args=(p,), daemon=True)
+          for p in range(STRESS_THREADS)]
+    switch = sys.getswitchinterval()
+    sys.setswitchinterval(1e-3)          # more interleavings per round
+    try:
+        for t in ts:
+            t.start()
+        deadline = time.monotonic() + 120
+        for t in ts:
+            t.join(max(0.0, deadline - time.monotonic()))
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(t.is_alive() for t in ts), "a waiter was never woken"
+
+    stats = obj.adapter.degree_stats(obj.core)
+    assert len(rounds) == stats["rounds"]
+    assert rt.nvm.counters["psync"] - psyncs == stats["rounds"]
+    apply, final = _ref_counter() if kind == "counter" else _ref_heap()
+    got = [iter(r) for r in replies]
+    n = 0
+    for q, func, args in (e for r in rounds for e in r):
+        sent, reply = next(got[q])
+        assert args == sent
+        want = apply(func, args)
+        assert (type(reply), reply) == (type(want), want)
+        n += 1
+    assert n == STRESS_THREADS * STRESS_OPS
+    assert all(next(g, None) is None for g in got)
+    snap = obj.snapshot()
+    assert (sorted(snap) if kind == "heap" else snap) == final()
+    rt.close()

@@ -78,6 +78,87 @@ def test_waiter_polls_count_a_wait_when_it_ends():
     rt.close()
 
 
+def _hold_lock(core):
+    """Take the lock as a combiner would; returns the odd value held."""
+    core._elect.acquire()
+    held = core.lock.load() + 1
+    core.lock.store(held)
+    return held
+
+
+def test_a_thread_waiter_blocks_instead_of_polling():
+    rt = CombiningRuntime(n_threads=2)
+    obj = rt.make("counter", "pbcomb")
+    core = obj.core
+    held = _hold_lock(core)
+    t = threading.Thread(target=rt.attach(1).bind(obj).fetch_add, args=(5,),
+                         daemon=True)
+    t.start()
+    time.sleep(0.1)
+    core.lock.store(held + 1)
+    core._elect.release()
+    t.join(10)
+    assert not t.is_alive()
+    assert 1 <= _polls(obj) <= 2
+    assert obj.snapshot() == 5
+    rt.close()
+
+
+def test_a_plain_store_wakes_a_blocked_waiter_at_once():
+    rt = CombiningRuntime(n_threads=2)
+    core = rt.make("counter", "pbcomb").core
+    delays = []
+    for _ in range(5):
+        held = _hold_lock(core)
+        left = []
+
+        def wait():
+            core._wait_while(1, held)
+            left.append(time.perf_counter())
+        t = threading.Thread(target=wait, daemon=True)
+        t.start()
+        time.sleep(0.02)
+        assert not left                  # still waiting on the held lock
+        unlocked = time.perf_counter()
+        core.lock.store(held + 1)
+        t.join(10)
+        assert not t.is_alive()
+        core._elect.release()
+        delays.append(left[0] - unlocked)
+    assert sorted(delays)[2] < 0.010
+    rt.close()
+
+
+def test_an_shm_waiter_still_polls_and_leaves_on_halted():
+    from repro.core import SimulatedCrash
+    from repro.core.shm import ShmAtomicInt
+    rt = CombiningRuntime(n_threads=2, backend="shm")
+    try:
+        core = rt.make("counter", "pbcomb").core
+        assert isinstance(core.lock, ShmAtomicInt)
+        held = _hold_lock(core)
+        ended = []
+
+        def wait():
+            try:
+                core._wait_while(1, held)
+                ended.append("unlocked")
+            except SimulatedCrash:
+                ended.append("halted")
+        t = threading.Thread(target=wait, daemon=True)
+        t.start()
+        time.sleep(0.05)
+        assert not ended
+        rt.nvm.crash()
+        t.join(10)
+        assert not t.is_alive() and ended == ["halted"]
+        assert core.lock.load() == held  # the lock was never released
+        assert core.waiter_polls[1] == 0  # a wait that raises adds nothing
+        rt.recover()
+    finally:
+        rt.close()
+
+
 def _drive(rt, obj, calls, n_ops):
     """``n_ops`` calls per client thread, ``calls(bound, i)`` each."""
     def client(p):
