@@ -317,7 +317,8 @@ class ResponseLogObject(SeqObject):
         raise ValueError(f"unknown log op {func}")
 
     def vector_apply(self, nvm, st_base, func, args_list, ctx=None):
-        # KV/log record batches: d RECORDs scatter-scanned in one kernel
+        # KV/log record batches: d RECORDs in one kernel that flags the
+        # last write to each client, then only those words are written
         # (RECORD_MANY batches are tuples-of-tuples — eager path).
         if func != "RECORD":
             return None

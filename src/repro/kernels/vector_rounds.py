@@ -320,18 +320,15 @@ def _stack_builder(push: bool):
 def _log_builder(jnp, lax):
     # The log's resp words can hold rich (non-packable) payloads from
     # earlier eager RECORDs, so this kernel never reads existing state:
-    # it scans the batch into dense last-write-wins (seq, resp, touched)
-    # arrays and the caller scatters only the touched client words.
-    def k(seqs, resps, touched, cs, ss, rs):
-        def step(carry, x):
-            seqs, resps, touched = carry
-            c, s, r = x
-            return (seqs.at[c].set(s), resps.at[c].set(r),
-                    touched.at[c].set(1)), r
-
-        (seqs, resps, touched), outs = lax.scan(
-            step, (seqs, resps, touched), (cs, ss, rs))
-        return seqs, resps, touched, outs
+    # it sees only the batch's (client, seq, resp) columns and flags each
+    # entry no later entry of the batch names the same client for
+    # (last write wins, in batch order).  The caller scatters only the
+    # flagged entries, so nothing here is as wide as the log.
+    def k(cs, ss, rs):
+        idx = jnp.arange(cs.shape[0])
+        later = idx[None, :] > idx[:, None]
+        overwritten = jnp.any((cs[:, None] == cs[None, :]) & later, axis=1)
+        return ~overwritten, ss, rs
     return k
 
 
@@ -466,25 +463,25 @@ def stack_round(arr_words: Sequence[Any], size: Any, func: str,
 
 
 def log_round(n_clients: int, triples: Sequence[Tuple[Any, Any, Any]]):
-    """A batch of RECORD announcements as one last-write-wins scan.
+    """A batch of RECORD announcements, last write wins in batch order.
     Returns ``(writes, responses)`` where writes is a list of
-    ``(client, seq, resp)`` — one per client the batch touched — or
-    None."""
+    ``(client, seq, resp)`` — the last entry of the batch for each
+    client it names, in batch order — or None.  Host work and transfers
+    are the batch's length, whatever ``n_clients``."""
     with span("seam.gather"):
         cs = pack_ints([t[0] for t in triples])
         ss = pack_ints([t[1] for t in triples])
         rs = pack_ints([t[2] for t in triples])
-        zero = np.zeros(n_clients, dtype=np.int64)
     if cs is None or ss is None or rs is None:
         return None
     if len(cs) and (cs.min() < 0 or cs.max() >= n_clients):
         return None                      # eager path raises — keep it
-    seqs, resps, touched, outs = _run(
-        "log.RECORD", zero, zero, zero, cs, ss, rs)
+    last, seqs, resps = _run("log.RECORD", cs, ss, rs)
     with span("seam.scatter"):
-        writes = [(c, int(seqs[c]), int(resps[c]))
-                  for c in range(n_clients) if touched[c]]
-        return writes, outs.tolist()
+        keep = np.flatnonzero(last)
+        writes = list(zip(cs[keep].tolist(), seqs[keep].tolist(),
+                          resps[keep].tolist()))
+        return writes, resps.tolist()
 
 
 def ckpt_round(step: Any, pairs: Sequence[Tuple[Any, Any]]):

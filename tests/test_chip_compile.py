@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import vector_rounds
 
-WIDTH = 65_536      # heap capacity / response-log clients
+WIDTH = 65_536      # heap capacity
 DEGREE = 256        # announcements per combining round
 
 
@@ -56,7 +56,7 @@ _ARGS = {
                      ((DEGREE,), "int64")],
     "heap.HDELETEMIN": [((WIDTH,), "int64"), ((), "int64"),
                         ((DEGREE,), "int64")],
-    "log.RECORD": [((WIDTH,), "int64")] * 3 + [((DEGREE,), "int64")] * 3,
+    "log.RECORD": [((DEGREE,), "int64")] * 3,
     "ckpt.CKPT": [((), "int64"), ((DEGREE,), "int64"),
                   ((DEGREE,), "int64")],
 }
