@@ -212,6 +212,50 @@ class VClock:
         self._device_free = 0.0
 
 
+class LineRecorder:
+    """An NVM view for a writer whose lines are wanted: every call passes
+    through, and each write into ``[lo, lo + n)`` notes the cache lines
+    it covers, numbered from ``lo`` (line-aligned).  PBComb writes a
+    round's StateRec through one (DESIGN.md §3)."""
+
+    __slots__ = ("nvm", "lo", "hi", "lines")
+
+    def __init__(self, nvm: NVM, lo: int, n: int) -> None:
+        self.nvm = nvm
+        self.lo = lo
+        self.hi = lo + n
+        self.lines: set = set()
+
+    def write(self, addr: int, value: Any) -> None:
+        self.nvm.write(addr, value)
+        if self.lo <= addr < self.hi:
+            self.lines.add((addr - self.lo) // LINE)
+
+    def write_range(self, addr: int, values) -> None:
+        self.nvm.write_range(addr, values)
+        a, b = max(addr, self.lo), min(addr + len(values), self.hi)
+        if a < b:
+            self.lines.update(range((a - self.lo) // LINE,
+                                    (b - 1 - self.lo) // LINE + 1))
+
+    def runs(self) -> List[List[int]]:
+        """The noted lines as ``[offset, n_words]`` runs from ``lo``, in
+        order, merged where contiguous, cut at ``hi``."""
+        runs: List[List[int]] = []
+        end = self.hi - self.lo
+        for line in sorted(self.lines):
+            a = line * LINE
+            b = min(a + LINE, end)
+            if runs and runs[-1][0] + runs[-1][1] == a:
+                runs[-1][1] = b - runs[-1][0]
+            else:
+                runs.append([a, b - a])
+        return runs
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self.nvm, name)
+
+
 class NVM:
     #: Machine-off flag: False for the in-process NVM (a SimulatedCrash
     #: unwinds every thread synchronously, so no one keeps running).
@@ -589,25 +633,32 @@ class NVM:
             clock.advance(n_lines * clock.profile.pwb_ns)
             clock.sync_device(self._drain_cost_ns(drained))
 
-    def commit_round(self, state_addr: int, n_words: int,
-                     index_addr: int, index_value: Any,
+    def commit_round(self, runs, index_addr: int, index_value: Any,
                      pending=None) -> None:
         """PBComb's full round commit (Algorithm 2 lines 22-27):
         ``[persist_lines(pending);] pwb(StateRec); pfence;
         MIndex := v; pwb(&MIndex); psync`` — ``pending`` carries the
-        round's node touches (Algorithm 5 line 24)."""
+        round's node touches (Algorithm 5 line 24).  ``runs`` are the
+        ``(addr, n_words)`` runs of the StateRec that may differ from its
+        durable image, in order and merged: the whole record as one run
+        in the paper's form, fewer lines where PBComb goes by lines
+        (DESIGN.md §3).  Their write-back is one persistence event, one
+        crash tick even when there are none."""
         if not self._fast_ok():
             if pending:
                 self.persist_lines(pending)
-            self.pwb_range(state_addr, n_words)
+            if runs:
+                self.persist_lines(runs)
+            else:
+                self._tick_crash_point("pwb")
             self.pfence()
             self.write(index_addr, index_value)
             self.pwb(index_addr, 1)
             self.psync()
             return
-        runs = self._pending_lines(pending) if pending else ()
-        first = state_addr // LINE
-        n_lines = (state_addr + n_words - 1) // LINE - first + 1
+        pruns = self._pending_lines(pending) if pending else ()
+        state_runs = [(addr // LINE, (addr + n - 1) // LINE - addr // LINE
+                       + 1) for addr, n in runs]
         clock = self.clock
         drained: Optional[List[Tuple[int, int]]] = \
             [] if clock is not None else None
@@ -622,17 +673,21 @@ class NVM:
                     if drained is not None:
                         drained.append((efirst, en))
             n_pending = 0
-            for pfirst, pn in runs:
+            for pfirst, pn in pruns:
                 a = pfirst * LINE
                 b = a + pn * LINE
                 dur[a:b] = vol[a:b]
                 n_pending += pn
                 if drained is not None:
                     drained.append((pfirst, pn))
-            a, b = first * LINE, (first + n_lines) * LINE
-            dur[a:b] = vol[a:b]
-            if drained is not None:
-                drained.append((first, n_lines))
+            n_lines = 0
+            for sfirst, sn in state_runs:
+                a = sfirst * LINE
+                b = a + sn * LINE
+                dur[a:b] = vol[a:b]
+                n_lines += sn
+                if drained is not None:
+                    drained.append((sfirst, sn))
             vol[index_addr] = index_value
             iline = index_addr // LINE
             a = iline * LINE
@@ -652,7 +707,8 @@ class NVM:
             prof = clock.profile
             if n_pending:
                 clock.advance(n_pending * prof.pwb_ns)
-            clock.advance(n_lines * prof.pwb_ns)
+            if state_runs:
+                clock.advance(n_lines * prof.pwb_ns)
             clock.advance(prof.pfence_ns)
             clock.advance(1 * prof.pwb_ns)
             clock.sync_device(self._drain_cost_ns(drained))

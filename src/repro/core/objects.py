@@ -64,7 +64,10 @@ class SeqObject:
         ``read_range``/``write_range`` accessors, which cost zero NVM
         persistence instructions — the enclosing round's commit sentence
         persists the StateRec exactly as before, so modeled counters are
-        untouched by the vector path.  The base object declines always:
+        untouched by the vector path.  An object with a ``touch_plan``
+        writes only the words its per-op loop writes: PBComb persists
+        the lines a round wrote (DESIGN.md §3), and both paths must
+        persist the same ones.  The base object declines always:
         vectorization is opt-in per structure."""
         return None
 
@@ -171,9 +174,15 @@ class SeqQueueObject(SeqObject):
             return None
         ring2, h2, t2, resps = out
         with span("seam.scatter"):
-            nvm.write(st_base, h2)
-            nvm.write(st_base + 1, t2)
-            nvm.write_range(st_base + 2, ring2)
+            # only the words the per-op loop writes: head on a dequeue,
+            # tail and each filled slot on an enqueue
+            cap = self.capacity
+            for i in range(tail, t2):
+                nvm.write(st_base + 2 + i % cap, ring2[i % cap])
+            if t2 != tail:
+                nvm.write(st_base + 1, t2)
+            if h2 != head:
+                nvm.write(st_base, h2)
         return resps
 
     def touch_plan(self, nvm: NVM, st_base: int, func: str,
@@ -239,8 +248,12 @@ class SeqStackObject(SeqObject):
             return None
         arr2, s2, resps = out
         with span("seam.scatter"):
-            nvm.write(st_base, s2)
-            nvm.write_range(st_base + 1, arr2)
+            # only the words the per-op loop writes: the size, and each
+            # pushed slot
+            if s2 != size:
+                nvm.write(st_base, s2)
+            if s2 > size:
+                nvm.write_range(st_base + 1 + size, arr2[size:s2])
         return resps
 
     def touch_plan(self, nvm: NVM, st_base: int, func: str,

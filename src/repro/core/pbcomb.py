@@ -20,7 +20,9 @@ Faithful implementation of Algorithms 1 and 2.  Design decisions
      recovery (P1).
 
 Per combining round of degree d: pwb(StateRec) + pfence + pwb(MIndex) +
-psync — i.e. O(1) persistence instructions for d requests.
+psync — i.e. O(1) persistence instructions for d requests.  Where the
+object names its writes (``touch_plan``), the StateRec copy and pwb
+cover only the lines in which the two StateRecs differ (DESIGN.md §3).
 
 StateRec NVM layout (contiguous, line-aligned):
     [ st : state_words | ReturnVal[0..n-1] | Deactivate[0..n-1] ]
@@ -35,7 +37,7 @@ from typing import Any, Optional
 
 from . import tracing
 from .atomics import Counters
-from .nvm import NVM
+from .nvm import NVM, LineRecorder
 from .objects import SeqObject
 from .tracing import span
 
@@ -102,6 +104,26 @@ class PBComb:
         sw = obj.state_words
         self.state_words = sw
         self.rec_words = sw + 2 * n_threads
+        # Copy and persist by lines (DESIGN.md §3).  An object with a
+        # ``touch_plan`` writes, on its vector path as in its per-op
+        # loop, only the words each op names, so a round copies into
+        # StateRec[ind], and persists from it, only the lines the last
+        # round wrote and the lines it writes itself, as a LineRecorder
+        # notes them.  The heap's kernel rewrites its whole array, and
+        # the heap, counter and float keep the paper's whole copy and
+        # write-back.  Thread backend only: shm's combiners are forked
+        # processes, and none sees the runs another's round left here.
+        # ``_last_runs``: the ``(offset, n_words)`` runs of the StateRec
+        # in which the two StateRecs may differ; the whole record after
+        # a crash, whose drain may leave them different anywhere.
+        self._by_lines = (hasattr(obj, "touch_plan")
+                          and nvm.backend.kind == "threads")
+        self._whole = [[0, self.rec_words]]
+        self._last_runs: list = [] if self._by_lines else self._whole
+        # the NVM the round body writes through: a LineRecorder while
+        # a by-lines round runs
+        self._writer: Any = nvm
+        self.copied_words = 0
         # --- shared non-volatile variables --------------------------- #
         self.mem_base = [nvm.alloc(self.rec_words) for _ in range(2)]
         self.mindex_addr = nvm.alloc(1)
@@ -249,6 +271,8 @@ class PBComb:
                                           counters=self._counters)
         self.lockval = 0
         self._elect = be.reset_mutex(self._elect)  # may be held at crash
+        self._last_runs = self._whole   # next round copies, persists whole
+        self._writer = self.nvm
         for p in range(self.n):
             self.resync_request(p)
 
@@ -316,14 +340,20 @@ class PBComb:
         line 8: while the lock is held nobody else writes it, so the
         line 24 read and line 28 increment are plain arithmetic."""
         nvm = self.nvm
-        wr = nvm.write
         clk = self._clock
         if clk is not None:
             clk.advance(clk.profile.round_ns)   # round fusion bookkeeping
         mindex = nvm.read(self.mindex_addr)
         ind = 1 - mindex                                     # line 14
         base = self.mem_base[ind]
-        nvm.copy_range(base, self.mem_base[mindex], self.rec_words)  # line 15
+        mbase = self.mem_base[mindex]
+        last = self._last_runs
+        for off, n in last:                                  # line 15
+            nvm.copy_range(base + off, mbase + off, n)
+            self.copied_words += n
+        if self._by_lines:
+            self._writer = LineRecorder(nvm, base, self.rec_words)
+        wr = self._writer.write
         self._round_served = 0
         self._begin_round(ind, p)
         retval_base = base + self.state_words
@@ -405,10 +435,17 @@ class PBComb:
                 break
         pending = self._post_simulation(ind, p)
         self.lockval = lock_val                              # line 24
+        runs = last
+        if self._by_lines:
+            written = self._writer.runs()
+            self._writer = nvm
+            runs = _union(last, written)
         # lines 22-23 + 25-27 as one fused commit (identical counters,
         # durable effect, and crash-tick behavior — see NVM.commit_round)
-        nvm.commit_round(base, self.rec_words, self.mindex_addr, ind,
-                         pending=pending)
+        nvm.commit_round([(base + off, n) for off, n in runs],
+                         self.mindex_addr, ind, pending=pending)
+        if self._by_lines:
+            self._last_runs = written
         # Measured degree: requests this committed round served (the
         # loop above plus any eliminated pairs _begin_round recorded).
         self.stats.record(served + self._round_served)
@@ -447,7 +484,7 @@ class PBComb:
     # ---------------- structure hooks --------------------------------- #
     def _apply(self, q: int, func: str, args: Any, ind: int,
                combiner: int) -> Any:
-        return self.obj.apply(self.nvm, self.mem_base[ind], func, args,
+        return self.obj.apply(self._writer, self.mem_base[ind], func, args,
                               ctx=self)
 
     def _apply_batch(self, batch, ind: int, combiner: int) -> list:
@@ -459,7 +496,7 @@ class PBComb:
         func = batch[0][1]
         if all(b[1] == func for b in batch):
             rets = self.obj.vector_apply(
-                self.nvm, self.mem_base[ind], func,
+                self._writer, self.mem_base[ind], func,
                 [b[2] for b in batch], ctx=self)
             if rets is not None:
                 return rets
@@ -468,11 +505,12 @@ class PBComb:
                     for q, f, a, _act in batch]
 
     def trace_counters(self) -> dict:
-        """Lock polls of waiting threads, and the queueing time and
-        count of the requests adopted while tracing was on, since the
-        core was made."""
+        """Lock polls of waiting threads, the queueing time and count of
+        the requests adopted while tracing was on, and the words the
+        line-15 copies moved, since the core was made."""
         return {"waiter_polls": sum(self.waiter_polls),
-                "queue_ns": self.queue_ns, "queued_ops": self.queued_ops}
+                "queue_ns": self.queue_ns, "queued_ops": self.queued_ops,
+                "copied_words": self.copied_words}
 
     def _begin_round(self, ind: int, combiner: int) -> None:
         """Called after the state copy, before the simulation loop.
@@ -488,3 +526,15 @@ class PBComb:
     def _pre_unlock(self, ind: int, combiner: int) -> None:
         """Called after psync, before the lock release.  PBQueue's enqueue
         instance publishes ``oldTail`` here (Algorithm 5 line 31)."""
+
+
+def _union(a: list, b: list) -> list:
+    """The union of two ordered lists of ``[offset, n_words]`` runs,
+    merged where they meet or overlap."""
+    out: list = []
+    for off, n in sorted(a + b):
+        if out and off <= out[-1][0] + out[-1][1]:
+            out[-1][1] = max(out[-1][1], off + n - out[-1][0])
+        else:
+            out.append([off, n])
+    return out

@@ -248,3 +248,32 @@ def test_tracing_switched_off_again_stops_counting():
     stats = obj.adapter.degree_stats(obj.core)
     assert stats["queued_ops"] == 0 and stats["queue_ns"] == 0
     rt.close()
+
+
+def test_copied_words_count_the_whole_record_then_the_last_rounds_lines():
+    """PBComb's ``copied_words``: the first round after ``recover()``
+    copies the whole StateRec, each later one only the lines the round
+    before it wrote (the log names its writes; DESIGN.md §3)."""
+    rt = CombiningRuntime(n_threads=2)
+    obj = rt.make("log", "pbcomb", n_clients=64)
+    rec = rt.attach(0).bind(obj).record
+
+    def copied(args):
+        before = obj.adapter.degree_stats(obj.core)["copied_words"]
+        rec(args)
+        return obj.adapter.degree_stats(obj.core)["copied_words"] - before
+
+    # StateRec: sessions in words 0..127 (lines 0..15), then thread 0's
+    # reply (word 128) and deactivate bit (word 130), in line 16 of
+    # its 132 words
+    words = obj.core.rec_words
+    assert words == 132
+    copied((5, 1, 7))
+    rt.crash()
+    rt.recover()
+    assert copied((40, 2, 8)) == words
+    # the last round wrote line 10 (session 40) and line 16 (4 words)
+    assert copied((20, 3, 9)) == 8 + 4
+    assert copied((20, 4, 9)) == 8 + 4       # line 5, line 16
+    assert copied((21, 5, 1)) == 8 + 4       # session 20 again: line 5
+    rt.close()

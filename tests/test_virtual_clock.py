@@ -110,9 +110,28 @@ def test_commit_round_fused_equals_discrete(profile, pending, prior):
         _prior_traffic(nvm, base, prior)
         pend = None if pending is None else \
             [(base + off, n) for off, n in pending]
-        nvm.commit_round(base, 40, idx, 1, pending=pend)
+        nvm.commit_round([(base, 40)], idx, 1, pending=pend)
         results.append(_observe(nvm))
     assert results[0] == results[1]          # floats bit-equal too
+
+
+STATE_RUN_CASES = [[], [(8, 8)], [(0, 16), (32, 8), (72, 8)]]
+
+
+@pytest.mark.parametrize("profile", sorted(PROFILES))
+@pytest.mark.parametrize("runs", STATE_RUN_CASES)
+@pytest.mark.parametrize("prior", PRIOR_CASES)
+def test_commit_round_by_runs_fused_equals_discrete(profile, runs, prior):
+    """A StateRec written back as several runs of lines, or as none
+    (PBComb by lines): the fused commit equals its discrete fallback."""
+    results = []
+    for force in (False, True):
+        nvm, base, idx = _prepared(profile, force)
+        _prior_traffic(nvm, base, prior)
+        nvm.commit_round([(base + off, n) for off, n in runs], idx, 1,
+                         pending=[(base + 3, 2)])
+        results.append(_observe(nvm))
+    assert results[0] == results[1]
 
 
 @pytest.mark.parametrize("profile", sorted(PROFILES))
@@ -158,7 +177,7 @@ if st is not None:
             nvm, base, idx = _prepared(profile, force)
             _prior_traffic(nvm, base, prior)
             pend = [(base + off, n) for off, n in pending]
-            nvm.commit_round(base, state_words, idx, 1,
+            nvm.commit_round([(base, state_words)], idx, 1,
                              pending=pend or None)
             results.append(_observe(nvm))
         assert results[0] == results[1]
