@@ -1,7 +1,10 @@
-"""Serve a (reduced) model with the combining batch engine: concurrent
-clients, continuous batching (= software combining), priority admission,
-a cancel eliminated in-flight, and a crash with detectable request
-recovery.
+"""Serve a jitted next-token function with the combining batch engine:
+concurrent clients, continuous batching (= software combining), priority
+admission, and a crash with detectable request recovery.
+
+The "model" is a stub, next token = (token * 31 + 7) % VOCAB, jitted
+over a fixed padded batch: the engine takes any batched prefill and
+decode functions, and the stub keeps the walkthrough about combining.
 
 Run:  PYTHONPATH=src python examples/serve_combining.py
 """
@@ -17,37 +20,27 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import CombiningRuntime
-from repro.configs import ARCHS
-from repro.models import decode_step, init_params, prefill
 from repro.serving.engine import CombiningEngine
 
-CFG = ARCHS["qwen3-1.7b"].smoke()
 FIXED_B = 4
+VOCAB = 1024
 
 
 def main():
-    params = init_params(CFG, jax.random.PRNGKey(0))
-    jit_prefill = jax.jit(lambda p, t: prefill(p, CFG, t, {}, max_len=48))
-    jit_decode = jax.jit(lambda p, s, t: decode_step(p, CFG, s, t))
-    shared = {}
+    # jit'd shapes are fixed; combiner batches are padded to FIXED_B
+    jit_next = jax.jit(lambda t: (t * 31 + 7) % VOCAB)
+
+    def next_tokens(last):
+        toks = list(last) + [0] * (FIXED_B - len(last))
+        nxt = np.asarray(jit_next(jnp.asarray(toks, jnp.int32)))
+        return [int(t) for t in nxt[:len(last)]]
 
     def prefill_batch(prompts):
-        L = max(len(p) for p in prompts)
-        rows = [list(p) + [0] * (L - len(p)) for p in prompts]
-        rows += [[0] * L] * (FIXED_B - len(rows))
-        logits, state = jit_prefill(params, jnp.asarray(rows, jnp.int32))
-        shared["state"] = state
-        first = np.asarray(jnp.argmax(logits, -1))
-        return [int(t) for t in first[:len(prompts)]], \
+        return next_tokens([p[-1] for p in prompts]), \
             list(range(len(prompts)))
 
     def decode_batch(kvs, last):
-        toks = list(last) + [0] * (FIXED_B - len(last))
-        logits, new_state = jit_decode(params, shared["state"],
-                                       jnp.asarray(toks, jnp.int32))
-        shared["state"] = new_state
-        nxt = np.asarray(jnp.argmax(logits, -1))
-        return [int(t) for t in nxt[:len(last)]]
+        return next_tokens(last)
 
     # The engine announces through a shared CombiningRuntime: the same
     # board/recovery plumbing every recoverable structure uses.

@@ -1,23 +1,10 @@
-"""Pallas TPU kernels for the framework's compute hot-spots.
+"""Jitted kernels of the combining runtime.
 
-The paper's contribution is synchronization/persistence (no kernel-level
-algorithm), so this package holds the TPU-native kernels for the model
-substrate's hot paths, each with a jit'd wrapper (ops.py) and a pure-jnp
-oracle (ref.py), validated in interpret mode:
+  vector_rounds — the VectorApply round bodies: one compiled kernel per
+                  (kind, op) runs a combining round's announced batch
+  scan_replay   — the periodic modeled-replay engine behind
+                  ``benchmarks/modeled.py``
 
-  flash_attention  — fused causal/windowed/softcap GQA attention
-                     (BlockSpec VMEM tiling, online softmax)
-  ssd_scan         — Mamba2 SSD chunked scan (sequential-grid VMEM
-                     state carry, MXU intra-chunk term)
-  decode_attention — flash-decode: one token vs a heads-major KV cache,
-                     streaming cache blocks with online softmax (the
-                     §Perf decode cell's endgame)
+The package imports nothing itself: ``from repro.kernels import
+vector_rounds`` loads only JAX and that module.
 """
-
-from .decode_attention import decode_attention
-from .flash_attention import flash_attention
-from .ops import attention_op, ssd_op
-from .ssd_scan import ssd_scan
-
-__all__ = ["decode_attention", "flash_attention", "attention_op",
-           "ssd_op", "ssd_scan"]

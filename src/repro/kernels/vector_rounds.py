@@ -16,7 +16,7 @@ Contract with ``SeqObject.vector_apply`` (core/objects.py):
   * Exactness: a kernel must produce byte-identical state words and
     responses to the per-op Python loop, or the caller must fall back.
     Kernels therefore run in 64-bit (``jax.enable_x64(True)`` scoped
-    to this module's calls — the model substrate stays f32) and
+    to this module's calls) and
     the packing guards reject anything that is not a plain Python int
     (or float, for the AtomicFloat kernel): rich payloads, huge ints,
     None — all take the eager path.  One documented wrinkle: ``bool``

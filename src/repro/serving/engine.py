@@ -32,8 +32,8 @@ announcement inside the combiner *before* touching engine state — both
 complete in one pass (the paper's push/pop elimination).
 
 The model is pluggable: ``prefill_batch_fn(prompts) -> (first_tok, kv)``
-and ``decode_batch_fn(kv_list, last_toks) -> next_toks`` — a real JAX
-model adapter lives in examples/serve_combining.py; tests use a toy LM.
+and ``decode_batch_fn(kv_list, last_toks) -> next_toks`` — a jitted
+adapter lives in examples/serve_combining.py; tests use a toy LM.
 """
 
 from __future__ import annotations

@@ -1,6 +1,4 @@
 import os
 import sys
 
-# Tests run against the real single CPU device — never the 512-device
-# dry-run environment (which only repro.launch.dryrun may create).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -172,10 +172,15 @@ def test_tape_provenance_and_helpers():
     assert [_next_pow2(n) for n in (1, 2, 3, 9)] == [1, 2, 4, 16]
 
 
-def test_modeled_matrix_rows():
+@pytest.fixture(scope="module")
+def matrix_rows():
+    return modeled.modeled_matrix()
+
+
+def test_modeled_matrix_rows(matrix_rows):
     """The CI-gated full-registry matrix: one deterministic row per
     (kind, protocol) cell, wall columns null, replay engine recorded."""
-    rows = modeled.modeled_matrix()
+    rows = matrix_rows
     names = [r["name"] for r in rows]
     assert len(names) == len(set(names))
     expected = {f"modeled_matrix/{k}/{p}" for k in registry.kinds()
@@ -194,3 +199,45 @@ def test_modeled_matrix_rows():
         else:
             assert r["rounds"] == modeled.MATRIX_ROUNDS_EAGER
             assert r["replay_engine"] == "eager"
+
+
+#: The paper's headline invariant: exact pwb and psync counts per op of
+#: every (kind, protocol) cell, as benchmarks/MODELED_baseline.json
+#: records them.  Combining pays 0.25 psync/op at 4 threads (one per
+#: round); every per-op-persist baseline pays 1.0.
+PERSISTS_PER_OP = {
+    ("ckpt", "lock-direct"): (0.25, 1.0),
+    ("ckpt", "lock-undo"): (3.25, 1.0),
+    ("ckpt", "pbcomb"): (0.75, 0.25),
+    ("ckpt", "pwfcomb"): (0.75, 0.25),
+    ("counter", "lock-direct"): (1.0, 1.0),
+    ("counter", "lock-undo"): (4.0, 1.0),
+    ("counter", "pbcomb"): (0.75, 0.25),
+    ("counter", "pwfcomb"): (0.75, 0.25),
+    ("heap", "lock-direct"): (129.0, 1.0),
+    ("heap", "lock-undo"): (260.0, 1.0),
+    ("heap", "pbcomb"): (8.75, 0.25),
+    ("heap", "pwfcomb"): (8.75, 0.25),
+    ("log", "lock-direct"): (1.0, 1.0),
+    ("log", "lock-undo"): (4.0, 1.0),
+    ("log", "pbcomb"): (0.75, 0.25),
+    ("log", "pwfcomb"): (1.0, 0.25),
+    ("queue", "durable-ms"): (2.0, 1.0),
+    ("queue", "lock-direct"): (1.497, 1.0),
+    ("queue", "lock-undo"): (4.497, 1.0),
+    ("queue", "pbcomb"): (1.0, 0.25),
+    ("queue", "pwfcomb"): (1.0, 0.25),
+    ("stack", "dfc"): (3.5, 0.25),
+    ("stack", "lock-direct"): (1.0, 1.0),
+    ("stack", "lock-undo"): (4.0, 1.0),
+    ("stack", "pbcomb"): (0.875, 0.25),
+    ("stack", "pwfcomb"): (0.875, 0.25),
+}
+
+
+@pytest.mark.parametrize("kind,protocol", sorted(PERSISTS_PER_OP))
+def test_modeled_matrix_exact_persists_per_op(matrix_rows, kind, protocol):
+    row = {r["name"]: r for r in matrix_rows}[
+        f"modeled_matrix/{kind}/{protocol}"]
+    assert (row["modeled_pwbs_per_op"], row["modeled_psyncs_per_op"]) \
+        == PERSISTS_PER_OP[kind, protocol]
