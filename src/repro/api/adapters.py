@@ -121,6 +121,14 @@ class StructureAdapter:
         return [self.recover(core, p, op, args, seq)
                 for op, args, seq in calls]
 
+    def recover_many(self, core: Any,
+                     records: List[Tuple[int, str, Any, int]]) -> List[Any]:
+        """Every in-flight ``(p, op, args, seq)`` of one object after a
+        crash; the replies in the order of ``records``.  One record at a
+        time, unless the core can recover them together."""
+        return [self.recover(core, p, op, args, seq)
+                for p, op, args, seq in records]
+
     # ---------------- optional paths ----------------------------------- #
     invoke_batch = None   # type: Optional[Any]  # set by batching adapters
 
@@ -201,6 +209,30 @@ class _CombiningAdapter(StructureAdapter):
 
     def perform(self, core, p, op):
         return self._instance(core, op)._perform_request(p)
+
+    def _recover_activate(self, inst: PBComb, p: int, seq: int) -> int:
+        """The activate bit Recover re-announces: the seq's parity."""
+        return seq % 2
+
+    def recover_many(self, core, records):
+        """PBComb instances recover their records together
+        (``PBComb.recover_many``): one round per instance that holds an
+        unserved record.  PWFComb keeps the one-at-a-time default."""
+        groups: Dict[PBComb, Tuple[list, list]] = {}
+        for i, (p, op, args, seq) in enumerate(records):
+            inst = self._instance(core, op)
+            if not isinstance(inst, PBComb):
+                return super().recover_many(core, records)
+            spec = self._spec(op)
+            at, recs = groups.setdefault(inst, ([], []))
+            at.append(i)
+            recs.append((p, spec.func, self._args(op, args),
+                         self._recover_activate(inst, p, seq)))
+        out: List[Any] = [None] * len(records)
+        for inst, (at, recs) in groups.items():
+            for i, ret in zip(at, inst.recover_many(recs)):
+                out[i] = ret
+        return out
 
     def _instances(self, core):
         """The distinct combining instances behind this core (split
@@ -317,7 +349,22 @@ class _ObjSnapshotMixin:
         return core.obj.snapshot(core.nvm, self._st(core))
 
 
-class PBLogAdapter(_ObjSnapshotMixin, _CombiningAdapter):
+class _ReexecuteMixin:
+    """Crash replay by re-executing the op as a fresh announcement, for
+    objects whose op is idempotent (the log's seq guard, the
+    checkpoint's step guard)."""
+
+    def recover(self, core, p, op, args, seq):
+        spec = self._spec(op)
+        return self._instance(core, op).op(p, spec.func,
+                                           self._args(op, args), seq)
+
+    def _recover_activate(self, inst, p, seq):
+        """A fresh announcement's flipped bit, as ``recover``'s ``op``."""
+        return 1 - inst.request[p].activate
+
+
+class PBLogAdapter(_ReexecuteMixin, _ObjSnapshotMixin, _CombiningAdapter):
     """Durable response log under PBComb — the serving engine's
     completion path as a registry structure (DESIGN.md §8).
 
@@ -335,11 +382,6 @@ class PBLogAdapter(_ObjSnapshotMixin, _CombiningAdapter):
         return PBComb(nvm, n_threads,
                       ResponseLogObject(n_clients or n_threads),
                       counters=counters, vector_apply=vector_apply)
-
-    def recover(self, core, p, op, args, seq):
-        spec = self._spec(op)
-        return self._instance(core, op).op(p, spec.func,
-                                           self._args(op, args), seq)
 
     def recover_batch(self, core, p, calls):
         triples = tuple(self._args(op, args) for op, args, _seq in calls)
@@ -373,7 +415,7 @@ class PWFLogAdapter(PBLogAdapter):
                        counters=counters, **kw)
 
 
-class PBCkptAdapter(_ObjSnapshotMixin, _CombiningAdapter):
+class PBCkptAdapter(_ReexecuteMixin, _ObjSnapshotMixin, _CombiningAdapter):
     """Checkpoint cell under PBComb: d announcers' persist requests ride
     one combining round/psync; newest step wins.  Replay is idempotent
     (the step guard), same reasoning as PBLogAdapter."""
@@ -383,11 +425,6 @@ class PBCkptAdapter(_ObjSnapshotMixin, _CombiningAdapter):
     def create(self, nvm, n_threads, counters=None, vector_apply=False, **kw):
         return PBComb(nvm, n_threads, CheckpointObject(),
                       counters=counters, vector_apply=vector_apply)
-
-    def recover(self, core, p, op, args, seq):
-        spec = self._spec(op)
-        return self._instance(core, op).op(p, spec.func,
-                                           self._args(op, args), seq)
 
 
 class PWFCkptAdapter(PBCkptAdapter):

@@ -20,8 +20,9 @@ once:
 (2) wipe every announcement board (volatile, P1), (3) rebuild each
 structure's volatile protocol state (locks, request arrays, S refs,
 pending-link redo...), (4) replay every in-flight operation recorded by
-the handles — the paper's system-support contract — returning the
-responses keyed by (object name, thread id).
+the handles — the paper's system-support contract, one
+``recover_many`` call per object — returning the responses keyed by
+(object name, thread id).
 """
 
 from __future__ import annotations
@@ -348,6 +349,7 @@ class CombiningRuntime:
         for name, tid, op, args, seq in inflight or ():
             inflight_map[(name, tid)] = (op, args, seq)
         responses: Dict[Tuple[str, int], Any] = {}
+        records: Dict[str, list] = {}
         for (name, tid), (op, a, seq) in inflight_map.items():
             obj = self.objects.get(name)
             if obj is None:
@@ -356,8 +358,14 @@ class CombiningRuntime:
                 responses[(name, tid)] = obj.adapter.recover_batch(
                     obj.core, tid, a)
             else:
-                responses[(name, tid)] = obj.adapter.recover(
-                    obj.core, tid, op, a, seq)
+                records.setdefault(name, []).append((tid, op, a, seq))
+        # each object's single records in one call, so a combining core
+        # serves them in one round (DESIGN.md §3)
+        for name, recs in records.items():
+            obj = self.objects[name]
+            replies = obj.adapter.recover_many(obj.core, recs)
+            for (tid, _op, _a, _seq), ret in zip(recs, replies):
+                responses[(name, tid)] = ret
         return responses
 
 

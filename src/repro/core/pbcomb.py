@@ -177,6 +177,10 @@ class PBComb:
         self.waiter_polls = [0] * n_threads
         self.queue_ns = 0
         self.queued_ops = 0
+        # Rounds that ``recover_many`` committed, and the requests they
+        # served, since the core was made (always counted).
+        self.recovery_rounds = 0
+        self.recovered_ops = 0
 
     # LockVal lives in a backend cell so a combiner process's write is
     # visible to waiter processes; property keeps the paper's name.
@@ -245,10 +249,29 @@ class PBComb:
         "system" for every thread that had an operation in flight at crash
         time, with the same arguments (Section 2's system-support
         assumption)."""
-        self.request[p] = RequestRec(func, args, seq % 2, 1)
-        if self.nvm.read(self._deact_addr(self._mindex(), p)) != seq % 2:
-            return self._perform_request(p)
-        return self.nvm.read(self._retval_addr(self._mindex(), p))
+        return self.recover_many([(p, func, args, seq % 2)])[0]
+
+    def recover_many(self, recs: list) -> list:
+        """Recover for many threads at once, as the system calls it for
+        every thread with an op in flight (DESIGN.md §3): line 3 for
+        each ``(p, func, args, activate)`` of ``recs`` first, then lines
+        4-6 for each in turn.  The first unserved thread's round adopts
+        every re-announced request, so the threads share one round and
+        one psync, and each later thread finds its request served and
+        reads its reply.  Returns the replies in the order of ``recs``."""
+        nvm = self.nvm
+        for p, func, args, act in recs:
+            self.request[p] = RequestRec(func, args, act, 1)    # line 3
+        out = []
+        for p, _func, _args, act in recs:
+            mindex = self._mindex()
+            if nvm.read(self._deact_addr(mindex, p)) != act:    # line 4
+                out.append(self._perform_request(p))            # line 5
+                self.recovery_rounds += 1
+                self.recovered_ops += self._round_served
+            else:
+                out.append(nvm.read(self._retval_addr(mindex, p)))  # line 6
+        return out
 
     def reset_volatile(self) -> None:
         """Re-initialize volatile protocol state after a crash (the crash
@@ -448,7 +471,8 @@ class PBComb:
             self._last_runs = written
         # Measured degree: requests this committed round served (the
         # loop above plus any eliminated pairs _begin_round recorded).
-        self.stats.record(served + self._round_served)
+        self._round_served += served
+        self.stats.record(self._round_served)
         if traced:
             self.queue_ns += queue_ns
             self.queued_ops += queued
@@ -506,11 +530,14 @@ class PBComb:
 
     def trace_counters(self) -> dict:
         """Lock polls of waiting threads, the queueing time and count of
-        the requests adopted while tracing was on, and the words the
-        line-15 copies moved, since the core was made."""
+        the requests adopted while tracing was on, the words the line-15
+        copies moved, and the rounds recovery committed and the requests
+        they served, since the core was made."""
         return {"waiter_polls": sum(self.waiter_polls),
                 "queue_ns": self.queue_ns, "queued_ops": self.queued_ops,
-                "copied_words": self.copied_words}
+                "copied_words": self.copied_words,
+                "recovery_rounds": self.recovery_rounds,
+                "recovered_ops": self.recovered_ops}
 
     def _begin_round(self, ind: int, combiner: int) -> None:
         """Called after the state copy, before the simulation loop.
