@@ -241,13 +241,15 @@ class _CombiningAdapter(StructureAdapter):
                      for op in self.OPS}.values())
 
     def degree_stats(self, core):
-        """The degree counters, with PBComb's ``trace_counters`` summed
-        over its instances (waiter polls, queueing time while traced)."""
+        """The degree counters, with each instance's ``trace_counters``
+        summed over the instances: PBComb's waiter polls, queueing time
+        while traced, copied words and recovery rounds; PWFComb's
+        attempts, how each ended, and the requests they wasted."""
         from ..core.backend import merge_degree_stats
         insts = self._instances(core)
         out = merge_degree_stats([inst.stats.snapshot() for inst in insts])
         for inst in insts:
-            if isinstance(inst, PBComb):
+            if isinstance(inst, (PBComb, PWFComb)):
                 for k, v in inst.trace_counters().items():
                     out[k] = out.get(k, 0) + v
         return out

@@ -43,10 +43,12 @@ import threading
 import time
 from typing import Any, Optional
 
+from . import tracing
 from .atomics import Counters
 from .nvm import NVM
 from .objects import SeqObject
 from .pbcomb import RequestRec
+from .tracing import span
 
 
 class _SRef:
@@ -96,6 +98,14 @@ class PWFComb:
     MAX_BACKOFF = 64  # spin iterations; adaptive, tiny on a 1-core host
     ANNOUNCE_PARK_PROB = 0.05
     ANNOUNCE_PARK_SECONDS = 1e-6   # OS floor applies
+    #: ``trace_counters`` keys.  An attempt (line 9 reached) ends
+    #: published, abandoned at a VL (lines 18, 26) or lost at the SC
+    #: (line 31), so attempts == rounds + aborted_vl + lost_sc; an op
+    #: ends in a publication of its own, a ``_try_finish`` hit or the
+    #: fallback (lines 38-50).  wasted_ops: requests applied in
+    #: attempts that did not publish.
+    TRACE_COUNTERS = ("attempts", "aborted_vl", "lost_sc",
+                      "finished_early", "fallbacks", "wasted_ops")
 
     def __init__(self, nvm: NVM, n_threads: int, obj: SeqObject,
                  counters: Optional[Counters] = None,
@@ -151,6 +161,9 @@ class PWFComb:
         # outside the scan (PWFStack's elimination) — attempts by
         # different threads run concurrently, hence one slot per tid
         self._attempt_served = [0] * n_threads
+        # TRACE_COUNTERS, always counted, each thread in its own slot
+        self._tally = [dict.fromkeys(self.TRACE_COUNTERS, 0)
+                       for _ in range(n_threads)]
 
     # ---------------- layout helpers ---------------------------------- #
     def _slot_id(self, owner: int, ind: int) -> int:
@@ -268,33 +281,68 @@ class PWFComb:
         return True, rd(self._retval_addr(self.S.load(), p))
 
     def _perform_request(self, p: int) -> Any:
-        nvm = self.nvm
-        rd, wr = nvm.read, nvm.write
-        clk = self._clock
-        my_slots = (self._slot_id(p, 0), self._slot_id(p, 1))
-        sw, n = self.state_words, self.n
+        tally = self._tally[p]
         for _attempt in range(2):                                # line 5
             done, val = self._try_finish(p)
             if done:
+                tally["finished_early"] += 1
                 return val
-            ls, ver = self.S.ll()                                # line 9
-            ind = rd(self._base(ls) + sw + 2 * n + p)            # line 11
-            dst = my_slots[ind]
-            dst_base = self._base(dst)
-            nvm.copy_range(dst_base, self._base(ls), self.rec_words)  # line 13
-            wr(dst_base + sw + 3 * n, p)                         # line 14
-            lval = self.flush[p]                                 # line 15 (own, see module doc)
-            lval = lval + 1 if lval % 2 == 0 else lval + 2       # lines 16-17
-            if not self.S.vl(ver):                               # line 18
-                continue
-            self._attempt_served[p] = 0
-            self._begin_attempt(dst, p)
-            retval_base = dst_base + sw
-            deact_base = retval_base + n
-            request = self.request
-            comb_round = self.comb_round[p]
-            served = 0
-            batch = [] if self._vector_enabled else None
+            tally["attempts"] += 1
+            attempt = span("combine.attempt")
+            with attempt:
+                outcome, val, applied = self._attempt(p)
+                attempt.set_metadata(outcome=outcome)
+            if outcome == "published":
+                return val
+            tally["aborted_vl" if outcome == "vl" else "lost_sc"] += 1
+            if applied is not None:          # abandoned after simulating
+                tally["wasted_ops"] += applied
+                self._backoff(p, grow=True)                      # line 36
+        # Fallback (lines 38-50): request guaranteed served by now.
+        tally["fallbacks"] += 1
+        nvm = self.nvm
+        ls = self.S.load()                                       # line 38
+        s_pid = nvm.read(self._pid_addr(ls))
+        lval = self.flush[s_pid]                                 # line 40
+        if lval % 2 == 1:                                        # line 42 (see module doc)
+            nvm.pwb_sync(self.s_addr, 1)                         # lines 44-46
+            if lval == self.comb_round[s_pid][p]:
+                self._cas_flush(s_pid, lval, lval + 1)           # line 48
+        if self._clock is not None:
+            self._clock.merge(self._round_end_vt)        # Lamport hand-off
+        return nvm.read(self._retval_addr(self.S.load(), p))     # line 50
+
+    def _attempt(self, p: int):
+        """One attempt, lines 9-35.  Returns ``(outcome, response,
+        applied)``: outcome ``published`` (with p's response), ``vl``
+        (abandoned at line 18 or 26) or ``sc`` (line 31 lost); applied
+        is the number of requests the simulation applied, None where
+        line 18 abandoned the attempt before it."""
+        nvm = self.nvm
+        rd, wr = nvm.read, nvm.write
+        clk = self._clock
+        sw, n = self.state_words, self.n
+        ls, ver = self.S.ll()                                    # line 9
+        ind = rd(self._base(ls) + sw + 2 * n + p)                # line 11
+        dst = self._slot_id(p, ind)
+        dst_base = self._base(dst)
+        nvm.copy_range(dst_base, self._base(ls), self.rec_words)  # line 13
+        wr(dst_base + sw + 3 * n, p)                             # line 14
+        lval = self.flush[p]                                     # line 15 (own, see module doc)
+        lval = lval + 1 if lval % 2 == 0 else lval + 2           # lines 16-17
+        if not self.S.vl(ver):                                   # line 18
+            return "vl", None, None
+        self._attempt_served[p] = 0
+        self._begin_attempt(dst, p)
+        retval_base = dst_base + sw
+        deact_base = retval_base + n
+        request = self.request
+        comb_round = self.comb_round[p]
+        traced = tracing.enabled
+        served = 0
+        batch = [] if self._vector_enabled else None
+        scan = span("combine.scan")
+        with scan:
             deacts = nvm.read_range(deact_base, n)    # one slice, n reads
             for q in range(n):                                   # line 19
                 req = request[q]
@@ -315,51 +363,48 @@ class PWFComb:
                     # batch below (merge-first is clock-identical)
                     batch.append((q, func, args, act))
                     continue
-                ret = self._apply(q, func, args, dst, p)        # lines 21-22
-                wr(retval_base + q, ret)                            # line 23
-                wr(deact_base + q, act)                             # line 24
-                comb_round[q] = lval                                # line 25
+                if traced:                                       # lines 21-22
+                    with span("combine.host_apply"):
+                        ret = self._apply(q, func, args, dst, p)
+                else:
+                    ret = self._apply(q, func, args, dst, p)
+                wr(retval_base + q, ret)                         # line 23
+                wr(deact_base + q, act)                          # line 24
+                comb_round[q] = lval                             # line 25
                 served += 1
-            if batch:
-                rets = self._apply_batch(batch, dst, p)
-                for (q, _f, _a, act), ret in zip(batch, rets):
-                    wr(retval_base + q, ret)                        # line 23
-                    wr(deact_base + q, act)                         # line 24
-                    comb_round[q] = lval                            # line 25
-                served = len(batch)
-            if self.S.vl(ver):                                   # line 26
-                index_addr = deact_base + n + p
-                wr(index_addr, 1 - rd(index_addr))               # line 27
-                pending = self._pre_publish(dst, p)
-                nvm.pwb_fence(dst_base, self.rec_words,
-                              pending=pending)                   # lines 28-29
-                self.flush[p] = lval                             # line 30
-                if self.S.sc(ver, dst):                          # line 31
-                    nvm.pwb_sync(self.s_addr, 1)                 # lines 32-33
-                    self._cas_flush(p, lval, lval + 1)           # line 34
-                    # Measured degree: requests this publication served
-                    # in one pwb(S)+psync (scan + eliminated pairs).
-                    self.stats.record(served + self._attempt_served[p])
-                    if clk is not None:
-                        clk.advance(clk.profile.round_ns)
-                        self._round_end_vt = clk.now()
-                    # Hook runs after S is durable: safe point to recycle
-                    # nodes the published round removed.
-                    self._on_publish_success(dst, p)
-                    return nvm.read(self._retval_addr(self.S.load(), p))  # line 35
+            scan.set_metadata(adopted=len(batch) if batch is not None
+                              else served)
+        if batch:
+            rets = self._apply_batch(batch, dst, p)
+            for (q, _f, _a, act), ret in zip(batch, rets):
+                wr(retval_base + q, ret)                         # line 23
+                wr(deact_base + q, act)                          # line 24
+                comb_round[q] = lval                             # line 25
+            served = len(batch)
+        if not self.S.vl(ver):                                   # line 26
             self._attempt_failed(dst, p)
-            self._backoff(p, grow=True)                          # line 36
-        # Fallback (lines 38-50): request guaranteed served by now.
-        ls = self.S.load()                                       # line 38
-        s_pid = nvm.read(self._pid_addr(ls))
-        lval = self.flush[s_pid]                                 # line 40
-        if lval % 2 == 1:                                        # line 42 (see module doc)
-            nvm.pwb_sync(self.s_addr, 1)                         # lines 44-46
-            if lval == self.comb_round[s_pid][p]:
-                self._cas_flush(s_pid, lval, lval + 1)           # line 48
+            return "vl", None, served
+        index_addr = deact_base + n + p
+        wr(index_addr, 1 - rd(index_addr))                       # line 27
+        pending = self._pre_publish(dst, p)
+        nvm.pwb_fence(dst_base, self.rec_words, pending=pending)  # lines 28-29
+        self.flush[p] = lval                                     # line 30
+        if not self.S.sc(ver, dst):                              # line 31
+            self._attempt_failed(dst, p)
+            return "sc", None, served
+        nvm.pwb_sync(self.s_addr, 1)                             # lines 32-33
+        self._cas_flush(p, lval, lval + 1)                       # line 34
+        # Measured degree: requests this publication served in one
+        # pwb(S)+psync (scan + eliminated pairs).
+        self.stats.record(served + self._attempt_served[p])
         if clk is not None:
-            clk.merge(self._round_end_vt)                # Lamport hand-off
-        return nvm.read(self._retval_addr(self.S.load(), p))     # line 50
+            clk.advance(clk.profile.round_ns)
+            self._round_end_vt = clk.now()
+        # Hook runs after S is durable: safe point to recycle nodes the
+        # published round removed.
+        self._on_publish_success(dst, p)
+        ret = nvm.read(self._retval_addr(self.S.load(), p))     # line 35
+        return "published", ret, served
 
     # ---------------- helpers ------------------------------------------ #
     def _cas_flush(self, i: int, old: int, new: int) -> None:
@@ -387,8 +432,15 @@ class PWFComb:
                 [b[2] for b in batch], ctx=self)
             if rets is not None:
                 return rets
-        return [self._apply(q, f, a, slot, combiner)
-                for q, f, a, _act in batch]
+        with span("combine.host_apply"):
+            return [self._apply(q, f, a, slot, combiner)
+                    for q, f, a, _act in batch]
+
+    def trace_counters(self) -> dict:
+        """``TRACE_COUNTERS`` summed over the threads, since the core
+        was made."""
+        return {k: sum(t[k] for t in self._tally)
+                for k in self.TRACE_COUNTERS}
 
     # ---------------- structure hooks ---------------------------------- #
     def _begin_attempt(self, slot: int, p: int) -> None:

@@ -42,6 +42,7 @@ vary per instance).
 from __future__ import annotations
 
 import os
+import threading
 from collections import Counter
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -53,6 +54,9 @@ _KERNELS: dict = {}
 #: jitted-round invocations per platform of the device that held the
 #: kernel's output ("cpu", "tpu", ...)
 _CALLS: Counter = Counter()
+#: guards ``_KERNELS`` and ``_CALLS``: concurrent PWFComb attempts call
+#: kernels from many threads at once
+_LOCK = threading.Lock()
 
 
 def _jx():
@@ -137,12 +141,15 @@ def kernel(name: str):
     profile."""
     fn = _KERNELS.get(name)
     if fn is None:
-        jax, jnp, lax = _jx()
-        body = BUILDERS[name](jnp, lax)
-        body.__name__ = body.__qualname__ = name.replace(".", "_")
-        with jax.enable_x64(True):
-            fn = jax.jit(body)
-        _KERNELS[name] = fn
+        with _LOCK:
+            fn = _KERNELS.get(name)
+            if fn is None:
+                jax, jnp, lax = _jx()
+                body = BUILDERS[name](jnp, lax)
+                body.__name__ = body.__qualname__ = name.replace(".", "_")
+                with jax.enable_x64(True):
+                    fn = jax.jit(body)
+                _KERNELS[name] = fn
     return fn
 
 
@@ -156,7 +163,8 @@ def _run(name: str, *args):
         with jax.enable_x64(True):
             out = fn(*args)
         (device,) = out[0].devices()
-        _CALLS[device.platform] += 1
+        with _LOCK:
+            _CALLS[device.platform] += 1
     with span("seam.fetch"):
         host = tuple(np.asarray(o) for o in out)
         # freeing the device outputs can give up the GIL as well
